@@ -31,6 +31,7 @@ from repro.core.collective import (camr_shuffle, camr_shuffle_reference,
 from repro.core.designs import make_design
 from repro.core.placement import make_placement
 from repro.core.schedule import lower_program
+from repro.launch.mesh import device_shortfall
 
 CONFIGS = [(2, 3), (4, 3), (3, 4), (2, 4), (5, 3)]
 
@@ -109,14 +110,18 @@ def _rows_local():
 def rows():
     """Suite entry point for benchmarks/run.py.
 
-    If another suite already initialized the jax backend (the XLA_FLAGS
+    If another suite already initialized the CPU backend (the XLA_FLAGS
     device-count hack at the top of this module only works before the
-    first jax import), re-run this module in a fresh subprocess and
-    relay its CSV rows.
+    first jax import), re-run this module in a fresh CPU subprocess and
+    relay its CSV rows. On any other platform it runs on the devices
+    this process holds, or refuses: a CPU child would time the CPU.
     """
     need = max(q * k for q, k in CONFIGS)
-    if len(jax.devices()) >= need:
+    devs = jax.devices()
+    if len(devs) >= need:
         return _rows_local()
+    if devs[0].platform != "cpu":
+        raise RuntimeError(device_shortfall(need, len(devs)))
     import csv
     import io
     import subprocess

@@ -48,6 +48,7 @@ from repro.compat import make_mesh
 from repro.core.collective import (ShuffleStream, make_plan,
                                    scatter_contributions)
 from repro.core.engine import CAMRConfig, CAMREngine
+from repro.launch.mesh import device_shortfall
 
 # (q, k, d) — d = the per-worker function-shard width being synced
 CONFIGS = [(2, 3, 256), (3, 3, 128), (2, 4, 96), (3, 4, 96), (5, 3, 64)]
@@ -214,15 +215,20 @@ def _bench_rows(smoke: bool, reps: int) -> list:
 def rows(smoke: bool | None = None):
     """Suite entry point for benchmarks/run.py.
 
-    If another suite already initialized the jax backend (the XLA_FLAGS
+    If another suite already initialized the CPU backend (the XLA_FLAGS
     device-count hack above only works before the first jax use),
-    re-run in a fresh subprocess and relay the CSV rows.
+    re-run in a fresh CPU subprocess and relay the CSV rows. On any
+    other platform it runs on the devices this process holds, or
+    refuses: a CPU child would time the CPU.
     """
     if smoke is None:
         smoke = os.environ.get("CAMR_BENCH_SMOKE", "") == "1"
     need = max(q * k for q, k, _ in (SMOKE_CONFIGS if smoke else CONFIGS))
-    if len(jax.devices()) >= need:
+    devs = jax.devices()
+    if len(devs) >= need:
         return _bench_rows(smoke, reps=5 if smoke else 15)
+    if devs[0].platform != "cpu":
+        raise RuntimeError(device_shortfall(need, len(devs)))
     import json
     import subprocess
     import tempfile

@@ -1,55 +1,28 @@
-"""Version portability for the two jax APIs this repo leans on.
+"""The repo's one spelling of a device mesh and of ``shard_map``.
 
-The repo targets the modern spelling (``jax.shard_map``, explicit
-``axis_types`` on ``jax.make_mesh``) but must also run on jax 0.4.x,
-where ``shard_map`` lives in ``jax.experimental.shard_map`` and meshes
-carry no axis types. Import ``make_mesh`` / ``shard_map`` from here
-instead of from ``jax`` directly.
-
-``shard_map`` here always disables the replication checker
-(``check_vma=False`` on new jax, ``check_rep=False`` on old): the CAMR
-collective bodies call Pallas kernels, which have no replication rule.
+Import ``make_mesh`` / ``shard_map`` from here instead of from ``jax``
+directly: meshes carry explicit ``Auto`` axis types, and ``shard_map``
+always runs with the replication checker off (``check_vma=False``) —
+the CAMR collective bodies call Pallas kernels, which have no
+replication rule.
 """
 
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 __all__ = ["make_mesh", "shard_map"]
 
 
-def make_mesh(axis_shapes, axis_names, **kw):
-    """``jax.make_mesh`` with Auto axis types where the API supports them."""
-    axis_shapes, axis_names = tuple(axis_shapes), tuple(axis_names)
-    mk = getattr(jax, "make_mesh", None)
-    if mk is None:  # jax < 0.4.35: build the Mesh directly
-        import numpy as np
-        devs = list(kw.pop("devices", None) or jax.devices())
-        n = 1
-        for s in axis_shapes:
-            n *= s
-        return jax.sharding.Mesh(
-            np.asarray(devs[:n]).reshape(axis_shapes), axis_names)
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        try:
-            return mk(axis_shapes, axis_names,
-                      axis_types=(axis_type.Auto,) * len(axis_names), **kw)
-        except TypeError:  # make_mesh predates axis_types
-            pass
-    return mk(axis_shapes, axis_names, **kw)
+def make_mesh(axis_shapes, axis_names, *, devices=None):
+    """``jax.make_mesh`` with every axis ``Auto``."""
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names),
+                         devices=devices)
 
 
 def shard_map(f, *, mesh, in_specs, out_specs):
-    """Portable ``shard_map`` with the replication checker off."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        try:
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_vma=False)
-        except TypeError:  # older spelling of the flag
-            return sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      check_rep=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with the replication checker off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)

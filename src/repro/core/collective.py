@@ -588,10 +588,10 @@ def camr_shuffle(plan: CAMRPlan, contribs: jnp.ndarray, *,
     delta cannot cancel between a payload fold and its checksum).
     ``corrupt=(stage, device, row, word, bits)`` XORs ``bits`` into
     one outgoing Δ word post-encode — the deterministic fault the
-    chaos layer replays. Requires the fused batched codec; the jnp
-    gather lane is forced (index tables are row-oriented, so the
-    widened rows reuse the same tables, but the u16 Pallas kernels
-    assume unaugmented packet geometry).
+    chaos layer replays. Requires the fused batched codec; the index
+    tables are row-oriented, so the widened rows reuse the same tables,
+    and 16-bit payloads pack to u32 words before widening (the u32
+    kernels carry them; the u16 kernels assume unaugmented packets).
     """
     prog = plan.program
     q, k, K, J, J_own, d = (plan.q, plan.k, plan.K, plan.J, plan.J_own,
@@ -618,14 +618,12 @@ def camr_shuffle(plan: CAMRPlan, contribs: jnp.ndarray, *,
         if debug:
             raise ValueError("verify_wire and debug are mutually "
                              "exclusive (different return shapes)")
-        use_kernels = False
-    else:
-        if corrupt is not None:
-            raise ValueError("corrupt injection without verify_wire "
-                             "would silently mis-reduce — exactly the "
-                             "failure mode the integrity lane exists "
-                             "to rule out")
-        use_kernels = _resolve_kernels(use_kernels)
+    elif corrupt is not None:
+        raise ValueError("corrupt injection without verify_wire "
+                         "would silently mis-reduce — exactly the "
+                         "failure mode the integrity lane exists "
+                         "to rule out")
+    use_kernels = _resolve_kernels(use_kernels)
     me = lax.axis_index(axis_name)
     # wire lane (DESIGN.md §12): wp u32 words per shard — d for 4-byte
     # dtypes, ceil(d/2) (+ pad to a packet multiple) for packed 16-bit
@@ -635,8 +633,10 @@ def camr_shuffle(plan: CAMRPlan, contribs: jnp.ndarray, *,
     def dev(tab):
         return jnp.take(jnp.asarray(tab), me, axis=0)
 
+    # [J_own, k-1, K, wp]; the integrity lane widens u32 words, so
+    # 16-bit payloads pack to u32 first and ride the u32 kernels
     wire = _wire_buffer(contribs, wp=wp, codec=codec,
-                        use_kernels=use_kernels)  # [J_own, k-1, K, wp]
+                        use_kernels=use_kernels and not verify_wire)
     pkv = pk
     if verify_wire:
         # widen every packet row from pk to pk+1 u32 words: payload +

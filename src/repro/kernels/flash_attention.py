@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import resolve_interpret
+
 __all__ = ["flash_attention"]
 
 _BLOCK_Q = 256
@@ -99,8 +101,10 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
                     softcap: float | None = None,
                     scale: float | None = None,
                     block_q: int = _BLOCK_Q, block_k: int = _BLOCK_K,
-                    interpret: bool = True) -> jnp.ndarray:
-    """q: [B, Hq, Tq, D]; k, v: [B, Hkv, Tk, D] -> [B, Hq, Tq, D]."""
+                    interpret: bool | None = None) -> jnp.ndarray:
+    """q: [B, Hq, Tq, D]; k, v: [B, Hkv, Tk, D] -> [B, Hq, Tq, D].
+
+    ``interpret=None`` compiles on TPU and interprets elsewhere."""
     B, Hq, Tq, D = q.shape
     _, Hkv, Tk, _ = k.shape
     if Hq % Hkv:
@@ -144,6 +148,6 @@ def flash_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
             pltpu.VMEM((bq, 1), jnp.float32),
             pltpu.VMEM((bq, D), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=resolve_interpret(interpret),
     )(q, k, v)
     return out[:, :, tq_pad - Tq:, :]

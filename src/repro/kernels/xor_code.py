@@ -40,10 +40,17 @@ Two kernel families (DESIGN.md §10):
   (encode output) and unpack (decode output) are same-width bitcasts —
   no 16-bit value ever widens to a 4-byte word in HBM.
 
-Tiling: grid over (row, word-block[, source]); each program XOR-folds
-lane-aligned ``(1, BLOCK)`` tiles held in VMEM. For the gather kernels
-the source axis is innermost, so the output tile stays resident in VMEM
-across the whole fold (one write-back per (row, block)).
+Tiling (DESIGN.md §10): every packet of ``width`` words is viewed as
+``[rows, 128]`` lane rows (a row-major reshape of the zero-padded row),
+and a block is a whole number of the chip's native ``(8, 128)`` u32 /
+``(16, 128)`` u16 tiles, or the whole of a short packet. The packet-row
+gather rides the leading (squeezed) block dimension, which the TPU
+compiler leaves unconstrained — a ``(1, BLOCK)`` row block breaks its
+(8, 128) rule. Grid over (row, word-block[, source]); for the gather
+kernels the source axis is innermost, so the output tile stays resident
+in VMEM across the whole fold (one write-back per (row, block)).
+Masked-off sources are selected to the XOR identity in VMEM by a
+scalar-prefetched 0/1 flag, never branched on.
 """
 
 from __future__ import annotations
@@ -55,31 +62,44 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from .mode import resolve_interpret
+
 __all__ = ["xor_encode", "xor_fold", "xor_decode",
            "xor_encode_gather", "xor_decode_gather",
            "xor_encode_gather16", "xor_decode_gather16"]
 
-_BLOCK = 1024  # u32 words per tile; multiple of the 128-lane VPU width
+_BLOCK = 1024  # words per block: one (8, 128) u32 tile
 _LANE = 128
 
 
-def _tile(pk: int, block: int) -> tuple[int, int]:
-    """Lane-aligned (block, padded_pk) for a packet width ``pk``."""
-    blk = min(block, -(-pk // _LANE) * _LANE)
-    return blk, -(-pk // blk) * blk
+def _tile(width: int, block: int, dtype) -> tuple[int, int]:
+    """``(rows per block, padded rows)`` of a ``width``-word packet laid
+    out as ``[rows, 128]``: a block is a whole number of native sublane
+    tiles (8 rows of u32, 16 of u16) or, for a short packet, all of it."""
+    sub = 32 // jnp.dtype(dtype).itemsize
+    rows = -(-width // _LANE)
+    br = max(sub, block // _LANE // sub * sub)
+    if rows <= br:
+        return rows, rows
+    return br, -(-rows // br) * br
 
 
-def _mask_words(mask: jnp.ndarray) -> jnp.ndarray:
-    """bool -> u32 0x00000000/0xFFFFFFFF (AND-applicable mask words)."""
-    return jnp.where(mask, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+def _rows(x: jnp.ndarray, rows: int) -> jnp.ndarray:
+    """``[..., width]`` -> zero-padded lane-row view ``[..., rows, 128]``
+    (XOR identity is 0, so padding never leaks into real words)."""
+    pad = rows * _LANE - x.shape[-1]
+    x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+    return x.reshape(*x.shape[:-1], rows, _LANE)
 
 
-def _resolve_interpret(interpret) -> bool:
-    """``interpret=None`` -> compiled Mosaic on TPU, interpreter elsewhere
-    (CPU/GPU have no Mosaic lowering for these kernels)."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
+def _unrows(y: jnp.ndarray, width: int) -> jnp.ndarray:
+    """Inverse of :func:`_rows`: ``[..., rows, 128]`` -> ``[..., width]``."""
+    return y.reshape(*y.shape[:-2], -1)[..., :width]
+
+
+def _masked(tile, flag):
+    """``tile`` where ``flag`` else the XOR identity — a select, no branch."""
+    return jnp.where(flag != 0, tile, jnp.zeros_like(tile))
 
 
 def _xor_kernel(p_ref, o_ref, *, m: int):
@@ -99,7 +119,7 @@ def xor_encode(packets: jnp.ndarray, *, block: int = _BLOCK,
     """
     if packets.dtype != jnp.uint32:
         raise TypeError("xor_encode expects uint32")
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     m, n = packets.shape
     n_pad = -(-n // block) * block
     x = jnp.pad(packets, ((0, 0), (0, n_pad - n)))
@@ -114,13 +134,6 @@ def xor_encode(packets: jnp.ndarray, *, block: int = _BLOCK,
     return out[:n]
 
 
-def _fold_kernel(p_ref, o_ref, *, m: int):
-    acc = p_ref[0, 0]
-    for i in range(1, m):
-        acc = acc ^ p_ref[0, i]
-    o_ref[0] = acc
-
-
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def xor_fold(packets: jnp.ndarray, *, block: int = _BLOCK,
              interpret: bool | None = None) -> jnp.ndarray:
@@ -131,27 +144,27 @@ def xor_fold(packets: jnp.ndarray, *, block: int = _BLOCK,
     """
     if packets.dtype != jnp.uint32:
         raise TypeError("xor_fold expects uint32")
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     R, m, n = packets.shape
-    n_pad = -(-n // block) * block
-    x = jnp.pad(packets, ((0, 0), (0, 0), (0, n_pad - n)))
+    br, rows = _tile(n, block, jnp.uint32)
     out = pl.pallas_call(
-        functools.partial(_fold_kernel, m=m),
-        grid=(R, n_pad // block),
-        in_specs=[pl.BlockSpec((1, m, block), lambda i, j: (i, 0, j))],
-        out_specs=pl.BlockSpec((1, block), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, n_pad), jnp.uint32),
+        functools.partial(_xor_kernel, m=m),
+        grid=(R, rows // br),
+        in_specs=[pl.BlockSpec((None, m, br, _LANE),
+                               lambda i, j: (i, 0, j, 0))],
+        out_specs=pl.BlockSpec((None, br, _LANE), lambda i, j: (i, j, 0)),
+        out_shape=jax.ShapeDtypeStruct((R, rows, _LANE), jnp.uint32),
         interpret=interpret,
-    )(x)
-    return out[:, :n]
+    )(_rows(packets, rows))
+    return _unrows(out, n)
 
 
-def _decode_kernel(r_ref, p_ref, m_ref, o_ref, *, m: int):
-    acc = r_ref[0]
-    for i in range(m):
-        # m_ref holds 0x00000000 / 0xFFFFFFFF: AND applies the mask
-        acc = acc ^ (p_ref[0, i] & m_ref[0, i])
-    o_ref[0] = acc
+def _decode_kernel(msk_ref, r_ref, p_ref, o_ref, *, m: int):
+    i = pl.program_id(0)
+    acc = r_ref[...]
+    for t in range(m):
+        acc = acc ^ _masked(p_ref[t], msk_ref[i, t])
+    o_ref[...] = acc
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -167,29 +180,30 @@ def xor_decode(recv: jnp.ndarray, packets: jnp.ndarray,
     """
     if recv.dtype != jnp.uint32 or packets.dtype != jnp.uint32:
         raise TypeError("xor_decode expects uint32")
-    interpret = _resolve_interpret(interpret)
+    interpret = resolve_interpret(interpret)
     R, m, n = packets.shape
     if recv.shape != (R, n):
         raise ValueError(f"recv shape {recv.shape} != {(R, n)}")
     if mask.shape != (R, m):
         raise ValueError(f"mask shape {mask.shape} != {(R, m)}")
-    n_pad = -(-n // block) * block
-    rv = jnp.pad(recv, ((0, 0), (0, n_pad - n)))
-    pk = jnp.pad(packets, ((0, 0), (0, 0), (0, n_pad - n)))
-    mk = jnp.where(mask, jnp.uint32(0xFFFFFFFF), jnp.uint32(0))
+    br, rows = _tile(n, block, jnp.uint32)
     out = pl.pallas_call(
         functools.partial(_decode_kernel, m=m),
-        grid=(R, n_pad // block),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda i, j: (i, j)),
-            pl.BlockSpec((1, m, block), lambda i, j: (i, 0, j)),
-            pl.BlockSpec((1, m), lambda i, j: (i, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, block), lambda i, j: (i, j)),
-        out_shape=jax.ShapeDtypeStruct((R, n_pad), jnp.uint32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(R, rows // br),
+            in_specs=[
+                pl.BlockSpec((None, br, _LANE), lambda i, j, _: (i, j, 0)),
+                pl.BlockSpec((None, m, br, _LANE),
+                             lambda i, j, _: (i, 0, j, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, br, _LANE),
+                                   lambda i, j, _: (i, j, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, rows, _LANE), jnp.uint32),
         interpret=interpret,
-    )(rv, pk, mk)
-    return out[:, :n]
+    )(mask.astype(jnp.int32), _rows(recv, rows), _rows(packets, rows))
+    return _unrows(out, n)
 
 
 # --------------------------------------------------------------------- #
@@ -198,7 +212,7 @@ def xor_decode(recv: jnp.ndarray, packets: jnp.ndarray,
 def _encode_gather_kernel(idx_ref, msk_ref, chunk_ref, o_ref):
     i = pl.program_id(0)
     j = pl.program_id(2)
-    term = chunk_ref[...] & msk_ref[i, j]
+    term = _masked(chunk_ref[...], msk_ref[i, j])
 
     @pl.when(j == 0)
     def _init():
@@ -209,6 +223,31 @@ def _encode_gather_kernel(idx_ref, msk_ref, chunk_ref, o_ref):
         o_ref[...] ^= term
 
 
+def _encode_gather(chunks, idx, mask, *, block, interpret):
+    """Shared body of the u32 and u16 fused encodes (dtype-generic)."""
+    n, m = idx.shape
+    if mask.shape != (n, m):
+        raise ValueError(f"mask shape {mask.shape} != {(n, m)}")
+    width = chunks.shape[1]
+    br, rows = _tile(width, block, chunks.dtype)
+    out = pl.pallas_call(
+        _encode_gather_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(n, rows // br, m),
+            in_specs=[
+                pl.BlockSpec((None, br, _LANE), lambda i, b, j, idx_r, _:
+                             (idx_r[i, j], b, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, br, _LANE),
+                                   lambda i, b, j, *_: (i, b, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((n, rows, _LANE), chunks.dtype),
+        interpret=resolve_interpret(interpret),
+    )(idx.astype(jnp.int32), mask.astype(jnp.int32), _rows(chunks, rows))
+    return _unrows(out, width)
+
+
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def xor_encode_gather(chunks: jnp.ndarray, idx: jnp.ndarray,
                       mask: jnp.ndarray, *, block: int = _BLOCK,
@@ -217,10 +256,10 @@ def xor_encode_gather(chunks: jnp.ndarray, idx: jnp.ndarray,
     ``out[i] = XOR_j { chunks[idx[i, j]] : mask[i, j] }``.
 
     ``chunks: u32[P, pk]`` is the flat packet view of the local chunk
-    buffer (``u32.reshape(-1, pk)`` — free); ``idx: i32[n, m]`` holds
+    buffer (``u32.reshape(-1, pk)``); ``idx: i32[n, m]`` holds
     flat packet-row sources (``enc_src`` of the schedule lowering) and
     ``mask: bool[n, m]`` their validity. Invalid entries must carry an
-    in-range index (the lowering bakes 0) — they are AND-masked to the
+    in-range index (the lowering bakes 0) — they are selected to the
     XOR identity inside VMEM, never branched on.
 
     The gather IS the block index map (scalar-prefetched tables), so
@@ -230,35 +269,15 @@ def xor_encode_gather(chunks: jnp.ndarray, idx: jnp.ndarray,
     """
     if chunks.dtype != jnp.uint32:
         raise TypeError("xor_encode_gather expects uint32")
-    interpret = _resolve_interpret(interpret)
-    n, m = idx.shape
-    if mask.shape != (n, m):
-        raise ValueError(f"mask shape {mask.shape} != {(n, m)}")
-    pk = chunks.shape[1]
-    blk, pkp = _tile(pk, block)
-    x = jnp.pad(chunks, ((0, 0), (0, pkp - pk)))
-    out = pl.pallas_call(
-        _encode_gather_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n, pkp // blk, m),
-            in_specs=[
-                pl.BlockSpec((1, blk), lambda i, b, j, idx_r, msk_r:
-                             (idx_r[i, j], b)),
-            ],
-            out_specs=pl.BlockSpec((1, blk), lambda i, b, j, *_: (i, b)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, pkp), jnp.uint32),
-        interpret=interpret,
-    )(idx.astype(jnp.int32), _mask_words(mask), x)
-    return out[:, :pk]
+    return _encode_gather(chunks, idx, mask, block=block,
+                          interpret=interpret)
 
 
 def _decode_gather_kernel(rsel_ref, idx_ref, msk_ref, recv_ref, chunk_ref,
                           o_ref):
     i = pl.program_id(0)
     j = pl.program_id(2)
-    term = chunk_ref[...] & msk_ref[i, j]
+    term = _masked(chunk_ref[...], msk_ref[i, j])
 
     @pl.when(j == 0)
     def _init():
@@ -267,6 +286,40 @@ def _decode_gather_kernel(rsel_ref, idx_ref, msk_ref, recv_ref, chunk_ref,
     @pl.when(j > 0)
     def _fold():
         o_ref[...] ^= term
+
+
+def _decode_gather(recv, chunks, rsel, idx, mask, *, block, interpret):
+    """Shared body of the u32 and u16 fused decodes (dtype-generic)."""
+    R, m = idx.shape
+    width = chunks.shape[1]
+    if recv.shape[1] != width:
+        raise ValueError(f"recv width {recv.shape[1]} != chunks width "
+                         f"{width}")
+    if rsel.shape != (R,):
+        raise ValueError(f"rsel shape {rsel.shape} != {(R,)}")
+    if mask.shape != (R, m):
+        raise ValueError(f"mask shape {mask.shape} != {(R, m)}")
+    br, rows = _tile(width, block, chunks.dtype)
+    out = pl.pallas_call(
+        _decode_gather_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(R, rows // br, m),
+            in_specs=[
+                pl.BlockSpec((None, br, _LANE), lambda i, b, j, rsel_r, *_:
+                             (rsel_r[i], b, 0)),
+                pl.BlockSpec((None, br, _LANE),
+                             lambda i, b, j, rsel_r, idx_r, _:
+                             (idx_r[i, j], b, 0)),
+            ],
+            out_specs=pl.BlockSpec((None, br, _LANE),
+                                   lambda i, b, j, *_: (i, b, 0)),
+        ),
+        out_shape=jax.ShapeDtypeStruct((R, rows, _LANE), chunks.dtype),
+        interpret=resolve_interpret(interpret),
+    )(rsel.astype(jnp.int32), idx.astype(jnp.int32), mask.astype(jnp.int32),
+      _rows(recv, rows), _rows(chunks, rows))
+    return _unrows(out, width)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -294,36 +347,8 @@ def xor_decode_gather(recv: jnp.ndarray, chunks: jnp.ndarray,
     """
     if recv.dtype != jnp.uint32 or chunks.dtype != jnp.uint32:
         raise TypeError("xor_decode_gather expects uint32")
-    interpret = _resolve_interpret(interpret)
-    R, m = idx.shape
-    pk = chunks.shape[1]
-    if recv.shape[1] != pk:
-        raise ValueError(f"recv width {recv.shape[1]} != chunks width {pk}")
-    if rsel.shape != (R,):
-        raise ValueError(f"rsel shape {rsel.shape} != {(R,)}")
-    if mask.shape != (R, m):
-        raise ValueError(f"mask shape {mask.shape} != {(R, m)}")
-    blk, pkp = _tile(pk, block)
-    rv = jnp.pad(recv, ((0, 0), (0, pkp - pk)))
-    x = jnp.pad(chunks, ((0, 0), (0, pkp - pk)))
-    out = pl.pallas_call(
-        _decode_gather_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(R, pkp // blk, m),
-            in_specs=[
-                pl.BlockSpec((1, blk), lambda i, b, j, rsel_r, *_:
-                             (rsel_r[i], b)),
-                pl.BlockSpec((1, blk), lambda i, b, j, rsel_r, idx_r, msk_r:
-                             (idx_r[i, j], b)),
-            ],
-            out_specs=pl.BlockSpec((1, blk), lambda i, b, j, *_: (i, b)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((R, pkp), jnp.uint32),
-        interpret=interpret,
-    )(rsel.astype(jnp.int32), idx.astype(jnp.int32), _mask_words(mask),
-      rv, x)
-    return out[:, :pk]
+    return _decode_gather(recv, chunks, rsel, idx, mask, block=block,
+                          interpret=interpret)
 
 
 # --------------------------------------------------------------------- #
@@ -336,13 +361,9 @@ def xor_decode_gather(recv: jnp.ndarray, chunks: jnp.ndarray,
 # never a widening: no value ever occupies 4 bytes in HBM on this lane
 # (the unpacked-u32 transient a cast-to-f32 shuffle would pay).
 # Tables are the SAME d-independent packet-row indices as the u32
-# kernels; only the lane count per packet doubles.
+# kernels; only the lane count per packet doubles, and a block holds
+# (16, 128) u16 tiles — the same bytes as the u32 lane's (8, 128).
 # --------------------------------------------------------------------- #
-def _mask_words16(mask: jnp.ndarray) -> jnp.ndarray:
-    """bool -> u16 0x0000/0xFFFF (AND-applicable mask lanes)."""
-    return jnp.where(mask, jnp.uint16(0xFFFF), jnp.uint16(0))
-
-
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def xor_encode_gather16(chunks: jnp.ndarray, idx: jnp.ndarray,
                         mask: jnp.ndarray, *, block: int = _BLOCK,
@@ -358,31 +379,11 @@ def xor_encode_gather16(chunks: jnp.ndarray, idx: jnp.ndarray,
     """
     if chunks.dtype != jnp.uint16:
         raise TypeError("xor_encode_gather16 expects uint16")
-    interpret = _resolve_interpret(interpret)
-    n, m = idx.shape
-    if mask.shape != (n, m):
-        raise ValueError(f"mask shape {mask.shape} != {(n, m)}")
-    pk2 = chunks.shape[1]
-    if pk2 % 2:
+    if chunks.shape[1] % 2:
         raise ValueError(f"packed packet lane count must be even, got "
-                         f"{pk2}")
-    blk, pkp = _tile(pk2, block)
-    x = jnp.pad(chunks, ((0, 0), (0, pkp - pk2)))
-    out = pl.pallas_call(
-        _encode_gather_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(n, pkp // blk, m),
-            in_specs=[
-                pl.BlockSpec((1, blk), lambda i, b, j, idx_r, msk_r:
-                             (idx_r[i, j], b)),
-            ],
-            out_specs=pl.BlockSpec((1, blk), lambda i, b, j, *_: (i, b)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((n, pkp), jnp.uint16),
-        interpret=interpret,
-    )(idx.astype(jnp.int32), _mask_words16(mask), x)
-    return out[:, :pk2]
+                         f"{chunks.shape[1]}")
+    return _encode_gather(chunks, idx, mask, block=block,
+                          interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
@@ -399,34 +400,5 @@ def xor_decode_gather16(recv: jnp.ndarray, chunks: jnp.ndarray,
     """
     if recv.dtype != jnp.uint16 or chunks.dtype != jnp.uint16:
         raise TypeError("xor_decode_gather16 expects uint16")
-    interpret = _resolve_interpret(interpret)
-    R, m = idx.shape
-    pk2 = chunks.shape[1]
-    if recv.shape[1] != pk2:
-        raise ValueError(f"recv width {recv.shape[1]} != chunks width "
-                         f"{pk2}")
-    if rsel.shape != (R,):
-        raise ValueError(f"rsel shape {rsel.shape} != {(R,)}")
-    if mask.shape != (R, m):
-        raise ValueError(f"mask shape {mask.shape} != {(R, m)}")
-    blk, pkp = _tile(pk2, block)
-    rv = jnp.pad(recv, ((0, 0), (0, pkp - pk2)))
-    x = jnp.pad(chunks, ((0, 0), (0, pkp - pk2)))
-    out = pl.pallas_call(
-        _decode_gather_kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
-            grid=(R, pkp // blk, m),
-            in_specs=[
-                pl.BlockSpec((1, blk), lambda i, b, j, rsel_r, *_:
-                             (rsel_r[i], b)),
-                pl.BlockSpec((1, blk), lambda i, b, j, rsel_r, idx_r, msk_r:
-                             (idx_r[i, j], b)),
-            ],
-            out_specs=pl.BlockSpec((1, blk), lambda i, b, j, *_: (i, b)),
-        ),
-        out_shape=jax.ShapeDtypeStruct((R, pkp), jnp.uint16),
-        interpret=interpret,
-    )(rsel.astype(jnp.int32), idx.astype(jnp.int32), _mask_words16(mask),
-      rv, x)
-    return out[:, :pk2]
+    return _decode_gather(recv, chunks, rsel, idx, mask, block=block,
+                          interpret=interpret)

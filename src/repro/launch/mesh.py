@@ -21,8 +21,8 @@ from repro.compat import make_mesh
 from repro.core.schedule import Topology
 
 __all__ = ["make_production_mesh", "data_axes", "mesh_devices",
-           "init_distributed", "make_camr_mesh", "detect_topology",
-           "host_membership"]
+           "init_distributed", "make_camr_mesh", "device_shortfall",
+           "detect_topology", "host_membership"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -53,12 +53,11 @@ def init_distributed(*, coordinator: str | None = None,
     """Bring up ``jax.distributed`` for multi-process execution.
 
     On a CPU backend, multi-process collectives need a cross-host
-    implementation — request gloo before initialize (a no-op on jax
-    builds without the option). Returns True when the distributed
-    runtime is (now) initialized, False when this build/environment
-    cannot (single-process fallback) — callers degrade to the flat
-    single-process lane rather than crash, and the subprocess smoke
-    test (tests/test_distributed.py) skips on False.
+    implementation — request gloo before initialize. Returns True when
+    the distributed runtime is (now) initialized, False when this
+    build/environment cannot (single-process fallback) — callers
+    degrade to the flat single-process lane rather than crash, and the
+    subprocess smoke test (tests/test_distributed.py) skips on False.
 
     MUST run before anything touches a backend: ``initialize`` rejects
     an already-materialized XLA client, so this function deliberately
@@ -66,12 +65,9 @@ def init_distributed(*, coordinator: str | None = None,
     init path (both instantiate the backend) and gates purely on
     exceptions.
     """
-    try:
-        # only meaningful for CPU backends; setting it is side-effect
-        # free elsewhere and must NOT query the backend to find out
-        jax.config.update("jax_cpu_collectives_implementation", "gloo")
-    except Exception:
-        pass                             # older jax: option absent
+    # only meaningful for CPU backends; setting it is side-effect free
+    # elsewhere and must NOT query the backend to find out
+    jax.config.update("jax_cpu_collectives_implementation", "gloo")
     try:
         jax.distributed.initialize(coordinator_address=coordinator,
                                    num_processes=num_processes,
@@ -95,10 +91,22 @@ def make_camr_mesh(K: int, *, axis_name: str = "camr"):
     """
     devs = jax.devices()
     if len(devs) < K:
-        raise ValueError(f"need {K} devices for the CAMR axis, have "
-                         f"{len(devs)} (set XLA_FLAGS="
-                         f"--xla_force_host_platform_device_count)")
+        raise ValueError(device_shortfall(K, len(devs)))
     return make_mesh((K,), (axis_name,), devices=devs[:K])
+
+
+def device_shortfall(K: int, have: int) -> str:
+    """Why a ``K = q*k``-worker shuffle cannot run on ``have`` devices,
+    and what to do about it on this platform."""
+    platform = jax.devices()[0].platform
+    msg = (f"a {K}-worker (q*k) CAMR shuffle needs {K} devices, one "
+           f"worker per device; this process sees {have} {platform} "
+           "device(s).")
+    if platform == "cpu":
+        return msg + (" On CPU set XLA_FLAGS=--xla_force_host_platform_"
+                      f"device_count={K} before importing jax.")
+    return msg + (" Folding several workers onto one chip is not built "
+                  "yet (ROADMAP Reach item 1).")
 
 
 def detect_topology(k: int, *, alpha: float = 4.0) -> Topology:

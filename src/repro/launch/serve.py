@@ -38,6 +38,7 @@ import numpy as np
 import jax
 
 from repro.configs import ARCHS, get_config, reduced
+from repro.launch.cache import use_compile_cache
 from repro.models import lm
 from repro.runtime.serve import (DecodeEngine, Request, ServeStream,
                                  serve_legacy)
@@ -50,6 +51,20 @@ def _percentile(xs, q):
 def _status_line(results) -> str:
     counts = Counter(r.status for r in results)
     return " ".join(f"{k}={v}" for k, v in sorted(counts.items()))
+
+
+def build_stream(cfgs: dict, params: dict, *, slots: int, page_size: int,
+                 max_ctx: int, max_new: int, wave: int,
+                 **policy) -> ServeStream:
+    """The engine path's serving stack: one :class:`DecodeEngine` per
+    arch (keyed like ``cfgs``/``params``) behind one multi-tenant
+    :class:`ServeStream`; ``policy`` holds the stream's self-healing
+    knobs (``max_queue``, ``wave_timeout_s``, ``max_retries``, ...)."""
+    engines = {a: DecodeEngine(cfg, params[a], slots=slots,
+                               page_size=page_size, max_ctx=max_ctx,
+                               max_new_cap=max_new, name=a)
+               for a, cfg in cfgs.items()}
+    return ServeStream(engines, wave_len=wave, **policy)
 
 
 def main():
@@ -89,6 +104,7 @@ def main():
                     help="base backoff between wave retries (doubles "
                          "per attempt)")
     args = ap.parse_args()
+    use_compile_cache()
 
     names = [a.strip() for a in args.archs.split(",") if a.strip()]
     for a in names:
@@ -148,21 +164,18 @@ def main():
               f"status: {_status_line(all_results)}")
         return
 
-    engines = {}
     for a in names:
         if cfgs[a].family == "encdec" or cfgs[a].frontend:
             ap.error(f"{a}: enc-dec/frontend archs need --legacy")
-        max_ctx = args.prompt_len + args.max_new
-        engines[a] = DecodeEngine(
-            cfgs[a], params[a], slots=args.slots,
-            page_size=args.page_size, max_ctx=max_ctx,
-            max_new_cap=args.max_new, name=a)
-    stream = ServeStream(engines, wave_len=args.wave,
-                         max_queue=args.max_queue,
-                         shed_policy=args.shed_policy,
-                         wave_timeout_s=args.wave_timeout_s,
-                         max_retries=args.max_retries,
-                         retry_backoff_s=args.retry_backoff_s)
+    stream = build_stream(cfgs, params, slots=args.slots,
+                          page_size=args.page_size,
+                          max_ctx=args.prompt_len + args.max_new,
+                          max_new=args.max_new, wave=args.wave,
+                          max_queue=args.max_queue,
+                          shed_policy=args.shed_policy,
+                          wave_timeout_s=args.wave_timeout_s,
+                          max_retries=args.max_retries,
+                          retry_backoff_s=args.retry_backoff_s)
     jobs = [(a, req) for a in names for req in requests_for(a)]
     t0 = time.perf_counter()
     results = stream.run(jobs)

@@ -32,6 +32,7 @@ import jax
 
 from repro.configs import ARCHS, SHAPES, get_config, reduced
 from repro.data.pipeline import ShardedTokenPipeline
+from repro.launch.cache import use_compile_cache
 from repro.runtime import MultiModelCAMRTrainer, Trainer
 
 
@@ -90,6 +91,7 @@ def main():
                     help="comma-separated failed worker ids (degraded "
                          "survivor-set schedule; --grad-sync camr only)")
     args = ap.parse_args()
+    use_compile_cache()
 
     if jax.process_count() > 1:  # multi-host pod
         jax.distributed.initialize()

@@ -381,11 +381,11 @@ class MultiModelCAMRTrainer:
         if self._stream is None:
             from repro.core.collective import ShuffleStream
             if self.mesh is None:
+                from repro.launch.mesh import device_shortfall
                 raise RuntimeError(
-                    f"mode='camr_spmd' needs a {self.K}-device mesh; this "
-                    f"process sees {len(jax.devices())} device(s). On CPU "
-                    "set XLA_FLAGS=--xla_force_host_platform_device_count="
-                    f"{self.K} before importing jax, or pass mesh=.")
+                    "mode='camr_spmd': "
+                    + device_shortfall(self.K, len(jax.devices()))
+                    + " Or pass mesh=.")
             self._stream = ShuffleStream(
                 self.q, self.k, self.d_shard, mesh=self.mesh,
                 axis_name=self.axis_name, mode="batched",

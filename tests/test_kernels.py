@@ -171,6 +171,25 @@ def test_aggregate_matches_ref(n, d, S, dtype):
                                rtol=1e-5, atol=1e-5)
 
 
+def test_aggregate_one_row_segments_bit_exact():
+    """gamma = 1 (one row per segment) is an exact gather over a wide
+    range of magnitudes: the trainer's bit-identity across sync modes
+    rests on it, and the MXU only multiplies bf16."""
+    rng = np.random.default_rng(3)
+    vals = (rng.standard_normal((48, 640))
+            * 10.0 ** rng.uniform(-25, 25, (48, 640))).astype(np.float32)
+    vals[3, 7] = -0.0                   # a sum gives +0.0, as the ref does
+    perm = rng.permutation(48).astype(np.int32)
+    got = np.asarray(aggregate(jnp.asarray(vals), jnp.asarray(perm), 48))
+    want = np.empty_like(vals)
+    want[perm] = np.float32(0) + vals
+    np.testing.assert_array_equal(got.view(np.uint32), want.view(np.uint32))
+    ref_out = np.asarray(ref.aggregate_ref(jnp.asarray(vals),
+                                           jnp.asarray(perm), 48))
+    np.testing.assert_array_equal(got.view(np.uint32),
+                                  ref_out.view(np.uint32))
+
+
 def test_aggregate_commutativity():
     """Associativity/commutativity of the α-combiner (Def. 1): permuting
     rows must not change the aggregates."""

@@ -263,6 +263,67 @@ def test_spmd_needs_mesh_actionable_error():
         tr.train_steps(pipe, 1, mode="camr_spmd")
 
 
+_RUN_TOO_FEW_DEVICES = textwrap.dedent("""
+    import jax
+    import numpy as np
+    from repro.configs import get_config, reduced
+    from repro.data.pipeline import ShardedTokenPipeline
+    from repro.launch.mesh import make_camr_mesh
+    from repro.runtime.train_loop import MultiModelCAMRTrainer
+
+    assert len(jax.devices()) == 4
+    cfg = reduced(get_config("granite_3_2b")).replace(
+        n_layers=2, vocab=64, d_model=32, d_ff=64, n_heads=2,
+        n_kv_heads=1, head_dim=16, loss_chunk=8)
+    tr = MultiModelCAMRTrainer(cfg, q=2, k=3, seed=0)
+    assert tr.mesh is None
+    pipe = ShardedTokenPipeline(vocab=64, seq_len=8, global_batch=2)
+    flat0 = np.asarray(tr.flat)
+    before = {id(a) for a in jax.live_arrays()}
+    try:
+        tr.train_steps(pipe, 1, mode="camr_spmd")
+    except RuntimeError as e:
+        msg = str(e)
+    else:
+        raise AssertionError("camr_spmd ran a 6-worker shuffle on 4 devices")
+    assert "needs 6 devices" in msg and "device_count=6" in msg, msg
+    placed = [a.shape for a in jax.live_arrays() if id(a) not in before]
+    assert not placed, placed
+    assert tr._stream is None and tr.step == 0
+    np.testing.assert_array_equal(np.asarray(tr.flat), flat0)
+    try:
+        make_camr_mesh(6)
+    except ValueError as e:
+        assert "needs 6 devices" in str(e), e
+    else:
+        raise AssertionError("make_camr_mesh(6) built a mesh on 4 devices")
+    print("OK")
+""")
+
+
+def test_spmd_refuses_fewer_than_K_devices_cleanly():
+    """On 4 devices a q*k = 6-worker camr_spmd step is refused before any
+    map or shuffle work: nothing new is placed on a device, the trainer
+    state is untouched, and the mesh builder refuses the same way."""
+    assert "OK" in _run_subprocess(_RUN_TOO_FEW_DEVICES, ndev=4)
+
+
+def test_device_shortfall_on_a_chip_host_names_the_missing_fold(
+        monkeypatch):
+    """Off the CPU no host-platform flag can add devices: the refusal
+    says a q*k-worker shuffle needs q*k devices and that folding workers
+    onto chips is a roadmap item, not a flag away."""
+    from repro.launch import mesh as mesh_mod
+
+    class _Chip:
+        platform = "tpu"
+
+    monkeypatch.setattr(mesh_mod.jax, "devices", lambda *a: [_Chip()] * 4)
+    msg = mesh_mod.device_shortfall(6, 4)
+    assert "needs 6 devices" in msg and "4 tpu device(s)" in msg
+    assert "ROADMAP Reach item 1" in msg and "XLA_FLAGS" not in msg
+
+
 def test_uncoded_rejects_degraded():
     """The uncoded baseline has no degraded mode and must say so;
     camr_spmd no longer rejects a failed set — it routes through the
