@@ -121,25 +121,32 @@ def attention_block(p, x, positions, cfg, *, window=None, softcap=None,
         npp = pt.shape[1]
         rows = jnp.arange(B)
         idx = cache_index
-        safe = jnp.maximum(idx, 0)
-        phys = jnp.where(idx < 0, 0, pt[rows, safe // ps])   # [B]
-        off = safe % ps                                       # [B]
-        kc = cache["k"].at[phys, :, off].set(k[:, :, 0])
-        vc = cache["v"].at[phys, :, off].set(v[:, :, 0])
+        with jax.named_scope("kv_write"):
+            safe = jnp.maximum(idx, 0)
+            phys = jnp.where(idx < 0, 0, pt[rows, safe // ps])   # [B]
+            off = safe % ps                                       # [B]
+            kc = cache["k"].at[phys, :, off].set(k[:, :, 0])
+            vc = cache["v"].at[phys, :, off].set(v[:, :, 0])
         new_cache = {"k": kc, "v": vc, "pages": pt}
         # gather the slot's pages back into logical order: the dense
         # per-row view the masked attention below consumes
-        k = kc[pt].transpose(0, 2, 1, 3, 4).reshape(B, hkv, npp * ps, dh)
-        v = vc[pt].transpose(0, 2, 1, 3, 4).reshape(B, hkv, npp * ps, dh)
+        with jax.named_scope("kv_gather"):
+            k = kc[pt].transpose(0, 2, 1, 3, 4).reshape(
+                B, hkv, npp * ps, dh)
+            v = vc[pt].transpose(0, 2, 1, 3, 4).reshape(
+                B, hkv, npp * ps, dh)
         valid_len = idx + T                       # [B]; -1 -> all masked
     elif cache is not None:
         # write this step's k/v at cache_index; keep the updated cache in
         # its sharded layout (a resharded DUS would replicate it)
         from repro.launch.partitioning import constrain as _con
-        kc = lax.dynamic_update_slice_in_dim(cache["k"], k, cache_index, 2)
-        vc = lax.dynamic_update_slice_in_dim(cache["v"], v, cache_index, 2)
-        kc = _con(kc, ("batch", None, "seq_kv", None))
-        vc = _con(vc, ("batch", None, "seq_kv", None))
+        with jax.named_scope("kv_write"):
+            kc = lax.dynamic_update_slice_in_dim(cache["k"], k,
+                                                 cache_index, 2)
+            vc = lax.dynamic_update_slice_in_dim(cache["v"], v,
+                                                 cache_index, 2)
+            kc = _con(kc, ("batch", None, "seq_kv", None))
+            vc = _con(vc, ("batch", None, "seq_kv", None))
         new_cache = {"k": kc, "v": vc}
         if T == 1:
             # decode: attend over the cache up to the current position

@@ -158,41 +158,44 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
     sp = ("batch", "seq", "embed")  # sequence-parallel residual layout
     if kind in ("attn", "local", "shared_attn"):
         window = cfg.local_window if kind == "local" else cfg.window
-        h = L.rms_norm(x, p["norm1"])
-        attn_cache = cache.get("self") if cache else None
-        h, new_self = L.attention_block(
-            p["attn"], h, positions, cfg, window=window,
-            softcap=cfg.attn_softcap, causal=(mode != "encoder"),
-            cache=attn_cache, cache_index=cache_index)
-        # reduce-scatter the row-parallel output into the SP layout
-        x = x + constrain(h, sp)
-        if cfg.family == "encdec" and kind == "attn" and mode != "encoder":
-            h = L.rms_norm(x, p["norm_x"])
-            if cache is not None and "cross" in cache:
-                # decode: attend to the prefilled cross k/v directly
-                ck = cache["cross"]
-                B = x.shape[0]
-                q = L.dense(h, p["cross"]["wq"]).reshape(
-                    B, x.shape[1], cfg.n_heads, cfg.hd).transpose(0, 2, 1, 3)
-                from repro.kernels import ops
-                o = ops.attention(q, ck["k"], ck["v"], causal=False,
-                                  use_pallas=cfg.use_pallas)
-                o = o.transpose(0, 2, 1, 3).reshape(B, x.shape[1], -1)
-                h = L.dense(o, p["cross"]["wo"])
-                new_cross = ck
-            else:
-                h, _ = L.attention_block(p["cross"], h, positions, cfg,
-                                         causal=False, memory=memory)
-                new_cross = None
+        # named scopes: a profile splits the step's device time by them
+        with jax.named_scope("attn"):
+            h = L.rms_norm(x, p["norm1"])
+            attn_cache = cache.get("self") if cache else None
+            h, new_self = L.attention_block(
+                p["attn"], h, positions, cfg, window=window,
+                softcap=cfg.attn_softcap, causal=(mode != "encoder"),
+                cache=attn_cache, cache_index=cache_index)
+            # reduce-scatter the row-parallel output into the SP layout
             x = x + constrain(h, sp)
-        else:
             new_cross = None
-        h = L.rms_norm(x, p["norm2"])
-        if cfg.n_experts:
-            h, aux = L.moe_block(p["moe"], h, cfg)
-        else:
-            h = L.mlp_block(p["mlp"], h, cfg)
-        x = x + constrain(h, sp)
+            if (cfg.family == "encdec" and kind == "attn"
+                    and mode != "encoder"):
+                h = L.rms_norm(x, p["norm_x"])
+                if cache is not None and "cross" in cache:
+                    # decode: attend to the prefilled cross k/v directly
+                    ck = cache["cross"]
+                    B = x.shape[0]
+                    q = L.dense(h, p["cross"]["wq"]).reshape(
+                        B, x.shape[1], cfg.n_heads, cfg.hd
+                    ).transpose(0, 2, 1, 3)
+                    from repro.kernels import ops
+                    o = ops.attention(q, ck["k"], ck["v"], causal=False,
+                                      use_pallas=cfg.use_pallas)
+                    o = o.transpose(0, 2, 1, 3).reshape(B, x.shape[1], -1)
+                    h = L.dense(o, p["cross"]["wo"])
+                    new_cross = ck
+                else:
+                    h, _ = L.attention_block(p["cross"], h, positions, cfg,
+                                             causal=False, memory=memory)
+                x = x + constrain(h, sp)
+        with jax.named_scope("mlp"):
+            h = L.rms_norm(x, p["norm2"])
+            if cfg.n_experts:
+                h, aux = L.moe_block(p["moe"], h, cfg)
+            else:
+                h = L.mlp_block(p["mlp"], h, cfg)
+            x = x + constrain(h, sp)
         new_cache = None
         if cache is not None:
             new_cache = {"self": new_self}
@@ -200,16 +203,18 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 new_cache["cross"] = new_cross
         return x, new_cache, aux
     if kind == "ssm":
-        h = L.rms_norm(x, p["norm"])
-        if mode == "prefill":
-            h, new_state = L.ssm_block(p["ssm"], h, cfg, state=None,
-                                       return_state=True)
-            new_cache = {"state": new_state}
-        else:
-            state = cache.get("state") if cache else None
-            h, new_state = L.ssm_block(p["ssm"], h, cfg, state=state)
-            new_cache = {"state": new_state} if cache is not None else None
-        return x + constrain(h, sp), new_cache, aux
+        with jax.named_scope("ssm"):
+            h = L.rms_norm(x, p["norm"])
+            if mode == "prefill":
+                h, new_state = L.ssm_block(p["ssm"], h, cfg, state=None,
+                                           return_state=True)
+                new_cache = {"state": new_state}
+            else:
+                state = cache.get("state") if cache else None
+                h, new_state = L.ssm_block(p["ssm"], h, cfg, state=state)
+                new_cache = ({"state": new_state} if cache is not None
+                             else None)
+            return x + constrain(h, sp), new_cache, aux
     raise ValueError(kind)
 
 
@@ -556,8 +561,9 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, cache_index):
         positions = jnp.full((B, 1), ci, jnp.int32)
     x, new_cache, _ = _scan_units(cfg, params, x, positions, cache=cache,
                                   cache_index=ci, mode="decode")
-    x = L.rms_norm(x, params["norm_f"])
-    return _logits(cfg, params, x), new_cache
+    with jax.named_scope("head"):
+        x = L.rms_norm(x, params["norm_f"])
+        return _logits(cfg, params, x), new_cache
 
 
 def poisoned_rows(logits, vocab: int):

@@ -59,6 +59,10 @@ __all__ = ["GenerationResult", "generate", "serve_legacy", "Request",
            "ServeStream", "ServeReport", "WaveCrashError",
            "WaveTimeoutError", "trace_total", "TRACE_COUNTS"]
 
+#: host spans in the profiler's trace (``serve.*``); about a microsecond
+#: each while no profiler is on
+_span = jax.profiler.TraceAnnotation
+
 #: terminal request statuses — every submitted request ends in exactly
 #: one of these, on both serving paths (DESIGN.md §15)
 STATUSES = ("ok", "expired", "shed", "quarantined", "retried_ok")
@@ -319,6 +323,11 @@ class ServeResult:
     status: str = "ok"
     #: wave retries survived while this request was live on a slot
     retries: int = 0
+    #: ``(time.monotonic(), tokens)`` per commit that delivered tokens,
+    #: after a first ``(admitted_at, 0)``: ``deliveries[1][0] -
+    #: deliveries[0][0]`` is the time to first token; the token counts
+    #: sum to ``emitted``. Empty for a request never admitted.
+    deliveries: list = field(default_factory=list, repr=False)
 
     @property
     def generated(self) -> np.ndarray:
@@ -488,34 +497,39 @@ class DecodeEngine:
 
                 def body(carry):
                     st, i = carry
-                    # 0. poisoned-slot sentinel (DESIGN.md §15): a live
-                    #    row whose carried logits went non-finite stops
-                    #    HERE — before its garbage sample could be
-                    #    emitted — so its buffer holds exactly the clean
-                    #    prefix. Rows are independent through sampling
-                    #    and decode, so siblings are undisturbed.
-                    bad = lm.poisoned_rows(st["logits"], vocab) \
-                        & ~st["done"]
-                    poison = st["poison"] | bad
-                    # 1. sample from the carried logits (the oracle's
-                    #    order: prefill logits feed the first token)
-                    keys, nxt = jax.vmap(sample_row)(
-                        st["keys"], st["logits"][:, :vocab], st["temp"])
-                    # a poisoned row's sample is garbage — feed the
-                    #    decode step its pad fill (a valid token id)
-                    nxt = jnp.where(bad, st["fill"], nxt)
-                    done = st["done"] | bad
-                    rows = jnp.arange(S)
-                    pos = jnp.minimum(st["emitted"], buf_T - 1)
-                    # finished rows re-write their current cell's value
-                    # (a no-op) so their tail stays at the pad fill
-                    old = st["buf"][rows, pos]
-                    buf = st["buf"].at[rows, pos].set(
-                        jnp.where(done, old, nxt))
-                    emitted = st["emitted"] + jnp.where(done, 0, 1)
-                    just_eos = ((~done) & (st["eos"] >= 0)
-                                & (nxt == st["eos"]))
-                    done2 = done | just_eos | (emitted >= st["cap"])
+                    with jax.named_scope("head"):
+                        # 0. poisoned-slot sentinel (DESIGN.md §15): a
+                        #    live row whose carried logits went
+                        #    non-finite stops HERE — before its garbage
+                        #    sample could be emitted — so its buffer
+                        #    holds exactly the clean prefix. Rows are
+                        #    independent through sampling and decode, so
+                        #    siblings are undisturbed.
+                        bad = lm.poisoned_rows(st["logits"], vocab) \
+                            & ~st["done"]
+                        poison = st["poison"] | bad
+                        # 1. sample from the carried logits (the
+                        #    oracle's order: prefill logits feed the
+                        #    first token)
+                        keys, nxt = jax.vmap(sample_row)(
+                            st["keys"], st["logits"][:, :vocab],
+                            st["temp"])
+                        # a poisoned row's sample is garbage — feed the
+                        #    decode step its pad fill (a valid token id)
+                        nxt = jnp.where(bad, st["fill"], nxt)
+                        done = st["done"] | bad
+                        rows = jnp.arange(S)
+                        pos = jnp.minimum(st["emitted"], buf_T - 1)
+                        # finished rows re-write their current cell's
+                        # value (a no-op) so their tail stays at the pad
+                        # fill
+                        old = st["buf"][rows, pos]
+                        buf = st["buf"].at[rows, pos].set(
+                            jnp.where(done, old, nxt))
+                        emitted = st["emitted"] + jnp.where(done, 0, 1)
+                        just_eos = ((~done) & (st["eos"] >= 0)
+                                    & (nxt == st["eos"]))
+                        done2 = done | just_eos | (emitted >= st["cap"])
                     # 2. device-side stop handling: finished rows write
                     #    to the trash page (index -1) and freeze length
                     ci = jnp.where(done2, -1, st["len"])
@@ -674,14 +688,16 @@ class DecodeEngine:
         row = np.zeros(self.pages_per_slot, np.int32)
         row[:n_total] = pages
         eos = -1 if req.eos is None else int(req.eos)
-        self.st = self._admit_fn(T)(
-            self.st, jnp.int32(slot), jnp.asarray(row), pre["cache"],
-            pre["logits"], jnp.int32(eos), jnp.int32(req.max_new),
-            jnp.float32(req.temperature), jnp.int32(req.fill),
-            jax.random.PRNGKey(req.seed))
+        with _span("serve.admit", req=handle):
+            self.st = self._admit_fn(T)(
+                self.st, jnp.int32(slot), jnp.asarray(row), pre["cache"],
+                pre["logits"], jnp.int32(eos), jnp.int32(req.max_new),
+                jnp.float32(req.temperature), jnp.int32(req.fill),
+                jax.random.PRNGKey(req.seed))
         self._live[slot] = {"handle": handle, "prompt_len": T,
                             "prompt": np.asarray(req.prompt, np.int32),
-                            "emitted_prev": 0, "retries": 0}
+                            "emitted_prev": 0, "retries": 0,
+                            "deliveries": [(time.monotonic(), 0)]}
         return slot
 
     # -- self-healing protocol (DESIGN.md §15) -------------------------- #
@@ -690,7 +706,8 @@ class DecodeEngine:
         flip the valid index (the commit point). Called at every wave
         boundary by :meth:`wave`."""
         nxt = 1 - self._snap_i
-        self._snaps[nxt] = self._snap_fn()(self.st)
+        with _span("serve.snapshot"):
+            self._snaps[nxt] = self._snap_fn()(self.st)
         self._snap_i = nxt
 
     def rollback(self) -> None:
@@ -705,7 +722,8 @@ class DecodeEngine:
             raise WaveCrashError(
                 f"engine {self.name!r}: no snapshot to roll back to "
                 "(crash before the first wave boundary)")
-        self.st = self._snap_fn()(snap)
+        with _span("serve.rollback"):
+            self.st = self._snap_fn()(snap)
         self.rollbacks += 1
 
     def mark_retried(self) -> None:
@@ -728,16 +746,19 @@ class DecodeEngine:
         ``(handle, ServeResult)`` carrying the clean tokens emitted so
         far."""
         h = self._live.pop(slot)
-        self.st = self._evict_fn()(self.st, jnp.int32(slot))
-        e = int(np.asarray(self.st["emitted"])[slot])
-        buf = np.asarray(self.st["buf"][slot, :e])
+        with _span("serve.evict", req=h["handle"]):
+            self.st = self._evict_fn()(self.st, jnp.int32(slot))
+            e = int(np.asarray(self.st["emitted"])[slot])
+            buf = np.asarray(self.st["buf"][slot, :e])
+        self._deliver(h, e, time.monotonic())
         self.pool.free(slot)
         self._free_slots.append(slot)
         self._free_slots.sort()
         res = ServeResult(
             tokens=np.concatenate([h["prompt"], buf]),
             prompt_len=h["prompt_len"], emitted=e, model=self.name,
-            status=status, retries=h["retries"])
+            status=status, retries=h["retries"],
+            deliveries=h["deliveries"])
         return h["handle"], res
 
     def run_wave(self, wave_len: int = 8, *, crash_hook=None) -> None:
@@ -757,13 +778,15 @@ class DecodeEngine:
         :meth:`rollback` to.
         """
         self.snapshot()
-        self.st = self._wave_fn(self.params, self.st,
-                                jnp.int32(wave_len))
+        with _span("serve.dispatch"):
+            self.st = self._wave_fn(self.params, self.st,
+                                    jnp.int32(wave_len))
         if crash_hook is not None:
             crash_hook(self)
         # honest attempt timing for the supervisor's timeout check: the
         # wave is only "done" when its buffers are
-        jax.block_until_ready(self.st["done"])
+        with _span("serve.block"):
+            jax.block_until_ready(self.st["done"])
 
     def commit_wave(self):
         """The HOST half of a wave: sync the finished set back, evict
@@ -771,19 +794,20 @@ class DecodeEngine:
         tokens_emitted, steps_run)`` where ``finished`` is a list of
         ``(slot, handle, ServeResult)``. Only call after the attempt is
         accepted — a committed wave cannot be rolled back."""
-        done = np.asarray(self.st["done"])
-        poison = np.asarray(self.st["poison"])
-        emitted = np.asarray(self.st["emitted"])
-        step = int(self.st["step"])
+        with _span("serve.sync"):
+            done = np.asarray(self.st["done"])
+            poison = np.asarray(self.st["poison"])
+            emitted = np.asarray(self.st["emitted"])
+            step = int(self.st["step"])
+            newly = [s for s in list(self._live) if done[s]]
+            buf = np.asarray(self.st["buf"]) if newly else None
+        t = time.monotonic()
         steps_run, self._step_prev = step - self._step_prev, step
         tokens = 0
         for s, h in self._live.items():
-            tokens += int(emitted[s]) - h["emitted_prev"]
-            h["emitted_prev"] = int(emitted[s])
-        newly = [s for s in list(self._live) if done[s]]
+            tokens += self._deliver(h, int(emitted[s]), t)
         finished = []
         if newly:
-            buf = np.asarray(self.st["buf"])
             for s in newly:
                 h = self._live.pop(s)
                 self.pool.free(s)
@@ -796,9 +820,19 @@ class DecodeEngine:
                     tokens=np.concatenate([h["prompt"], buf[s, :e]]),
                     prompt_len=h["prompt_len"], emitted=e,
                     model=self.name, status=status,
-                    retries=h["retries"])
+                    retries=h["retries"], deliveries=h["deliveries"])
                 finished.append((s, h["handle"], res))
         return finished, tokens, steps_run
+
+    @staticmethod
+    def _deliver(h: dict, emitted: int, t: float) -> int:
+        """Settle a live request's emitted count at time ``t``: record
+        the tokens it gained as one delivery; returns how many."""
+        m = emitted - h["emitted_prev"]
+        if m:
+            h["deliveries"].append((t, m))
+            h["emitted_prev"] = emitted
+        return m
 
     def wave(self, wave_len: int = 8, *, crash_hook=None):
         """One unsupervised wave: :meth:`run_wave` + :meth:`commit_wave`
@@ -825,13 +859,17 @@ class ServeReport:
     #: zero-recompilation admission contract; the RECOVERY path is held
     #: to the same bar)
     traces: int = 0
-    pipelined: bool = False
     #: wave retries paid by the supervisor (crashes + timeouts)
     retries: int = 0
     #: terminal-status histogram over this run's requests
     status_counts: dict = field(default_factory=dict)
     #: wall seconds spent on crashed/timed-out wave attempts + rollbacks
     recovery_s: float = 0.0
+
+
+def _prefill(eng: DecodeEngine, req: Request, idx: int) -> dict:
+    with _span("serve.prefill", req=idx):
+        return eng.prefill(req)
 
 
 class ServeStream:
@@ -916,7 +954,8 @@ class ServeStream:
                 if self.chaos is not None:
                     hook = (lambda e: self.chaos.on_wave_crash(
                         name, wave, e))
-                eng.run_wave(self.wave_len, crash_hook=hook)
+                with _span("serve.wave", wave=wave, attempt=attempt):
+                    eng.run_wave(self.wave_len, crash_hook=hook)
                 dt = time.perf_counter() - t0
                 if self.chaos is not None:
                     dt = self.chaos.on_wave_done(name, wave, eng, dt)
@@ -927,7 +966,8 @@ class ServeStream:
                     raise WaveTimeoutError(
                         f"{name!r} wave {wave}: {dt:.3f}s > "
                         f"wave_timeout_s={self.wave_timeout_s}")
-                fin, toks, steps = eng.commit_wave()
+                with _span("serve.commit", wave=wave):
+                    fin, toks, steps = eng.commit_wave()
                 return fin, toks, steps, dt, attempt, lost_s
             except (WaveCrashError, WaveTimeoutError):
                 lost_s += time.perf_counter() - t0
@@ -991,25 +1031,26 @@ class ServeStream:
                     q, pend = queues[name], pending[name]
                     # 0. deadline sweep (between waves): expire queued,
                     #    prefetched and LIVE requests past their budget
-                    for lane in (q, pend):
-                        for item in [it for it in lane
-                                     if deadline_at[it[0]] is not None
-                                     and now >= deadline_at[it[0]]]:
-                            lane.remove(item)
-                            terminal(item[0], "expired")
+                    with _span("serve.sweep"):
+                        for lane in (q, pend):
+                            for item in [it for it in lane
+                                         if deadline_at[it[0]] is not None
+                                         and now >= deadline_at[it[0]]]:
+                                lane.remove(item)
+                                terminal(item[0], "expired")
+                                progress = True
+                        for slot in [s for s, h in list(eng._live.items())
+                                     if deadline_at[h["handle"]] is not None
+                                     and now >= deadline_at[h["handle"]]]:
+                            handle, res = eng.evict(slot, "expired")
+                            res.model, res.index = name, handle
+                            results[handle] = res
                             progress = True
-                    for slot in [s for s, h in list(eng._live.items())
-                                 if deadline_at[h["handle"]] is not None
-                                 and now >= deadline_at[h["handle"]]]:
-                        handle, res = eng.evict(slot, "expired")
-                        res.model, res.index = name, handle
-                        results[handle] = res
-                        progress = True
                     # 1. top up the prefill prefetch lane
                     while q and len(pend) < self.prefetch:
                         idx, req = q.popleft()
                         if pool is not None:
-                            fut = pool.submit(eng.prefill, req)
+                            fut = pool.submit(_prefill, eng, req, idx)
                         else:
                             fut = None
                         pend.append((idx, req, fut))
@@ -1030,8 +1071,11 @@ class ServeStream:
                     # 3. admit prefilled requests into freed slots
                     while pend and eng.has_free_slot:
                         idx, req, fut = pend[0]
-                        pre = fut.result() if fut is not None \
-                            else eng.prefill(req)
+                        if fut is None:
+                            pre = _prefill(eng, req, idx)
+                        else:
+                            with _span("serve.prefill_wait", req=idx):
+                                pre = fut.result()
                         slot = eng.admit(req, pre, handle=idx)
                         if slot is None:
                             break                # pool pressure: wait
@@ -1052,6 +1096,6 @@ class ServeStream:
             requests=len(jobs), waves=waves, admitted=admitted,
             occupancy=(slot_steps / cap_steps) if cap_steps else 0.0,
             wave_stats=stats, traces=trace_total() - t_traces,
-            pipelined=self.pipeline, retries=retries,
+            retries=retries,
             status_counts=dict(counts), recovery_s=recovery_s)
         return results  # type: ignore[return-value]

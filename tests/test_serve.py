@@ -302,3 +302,72 @@ def test_serial_stream_matches_pipelined(gemma):
 
     for ra, rb in zip(run(True), run(False)):
         assert np.array_equal(ra.tokens, rb.tokens)
+
+
+def test_deliveries_sum_to_emitted_finished_and_evicted(gemma):
+    """Each result's deliveries start at its admission (0 tokens), their
+    token counts sum to ``emitted`` and their times never decrease, for
+    requests that finish and for one evicted mid-flight."""
+    cfg, params = gemma
+    reqs = [Request(prompt=p, max_new=n)
+            for p, n in zip(_prompts(cfg, [5, 8, 4, 6], seed=14),
+                            [5, 2, 4, 3])]
+    eng = DecodeEngine(cfg, params, slots=2, page_size=4, max_ctx=16,
+                       max_new_cap=5)
+    results = ServeStream(eng, wave_len=2).run(reqs)
+    evict = DecodeEngine(cfg, params, slots=2, page_size=4, max_ctx=16,
+                         max_new_cap=5)
+    slot = evict.admit(reqs[0], handle=0)
+    evict.wave(2)
+    _, cut = evict.evict(slot)
+    assert 0 < cut.emitted < reqs[0].max_new
+    for res in results + [cut]:
+        times = [t for t, _ in res.deliveries]
+        assert res.deliveries[0][1] == 0 and len(times) >= 2
+        assert sum(m for _, m in res.deliveries) == res.emitted
+        assert all(m > 0 for _, m in res.deliveries[1:])
+        assert times == sorted(times)
+
+
+def test_serving_spans_reach_the_profiler_trace(gemma, tmp_path):
+    """A profiled stream run carries every ``serve.*`` span of the
+    scheduler, engine and prefetch thread, with the request (``req``)
+    and wave (``wave``) they belong to."""
+    from jax.profiler import ProfileData
+
+    cfg, params = gemma
+    reqs = [Request(prompt=p, max_new=4)
+            for p in _prompts(cfg, [5, 8, 4], seed=15)]
+    eng = DecodeEngine(cfg, params, slots=2, page_size=4, max_ctx=16,
+                       max_new_cap=4)
+    stream = ServeStream(eng, wave_len=2)
+    stream.run(reqs)                                 # compile untraced
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        stream.run(reqs)
+        slot = eng.admit(reqs[0], handle=7)
+        eng.run_wave(2)          # an attempt the supervisor discards
+        eng.rollback()
+        eng.evict(slot)
+    finally:
+        jax.profiler.stop_trace()
+    spans = {}
+    for f in tmp_path.rglob("*.xplane.pb"):
+        for plane in ProfileData.from_file(str(f)).planes:
+            for line in plane.lines:
+                for ev in line.events:
+                    if ev.name.startswith("serve."):
+                        spans.setdefault(ev.name, []).append(
+                            {k: v for k, v in ev.stats})
+    assert set(spans) == {
+        "serve.sweep", "serve.wave", "serve.snapshot", "serve.dispatch",
+        "serve.block", "serve.commit", "serve.sync", "serve.prefill",
+        "serve.prefill_wait", "serve.admit", "serve.evict",
+        "serve.rollback"}
+    for name in ("serve.prefill", "serve.prefill_wait"):
+        assert {int(s["req"]) for s in spans[name]} == {0, 1, 2}
+    assert {int(s["req"]) for s in spans["serve.admit"]} == {0, 1, 2, 7}
+    assert [int(s["req"]) for s in spans["serve.evict"]] == [7]
+    waves = sorted(int(s["wave"]) for s in spans["serve.wave"])
+    assert waves == list(range(len(waves))) and waves
+    assert len(spans["serve.commit"]) == len(waves)
