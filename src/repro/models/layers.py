@@ -90,10 +90,12 @@ def spec_attention(cfg) -> Specs:
 
 def attention_block(p, x, positions, cfg, *, window=None, softcap=None,
                     causal=True, cache=None, cache_index=None,
-                    memory=None):
+                    memory=None, layer=None):
     """Self- (or cross-, when ``memory`` is set) attention.
 
     cache: optional dict(k=[B, Hkv, Tmax, Dh], v=...) -> returns updated.
+    With ``layer`` (decode) the cache is the layer-stacked ``[R, ...]``
+    array, written in place at ``layer`` and returned whole.
     """
     B, T, D = x.shape
     hq, hkv, dh = cfg.n_heads, cfg.n_kv_heads, cfg.hd
@@ -110,14 +112,14 @@ def attention_block(p, x, positions, cfg, *, window=None, softcap=None,
     valid_len = None
     if cache is not None and "pages" in cache:
         # paged slot-indexed layout (serving, DESIGN.md §13): k/v live in
-        # a shared page pool [P, Hkv, page, Dh]; ``pages`` [B, npp] maps
-        # each slot's logical pages to physical ones; ``cache_index`` is
-        # the per-row logical write position (-1 = finished row, its
+        # a shared page pool [R, P, Hkv, page, Dh]; ``pages`` [R, B, npp]
+        # maps each slot's logical pages to physical ones; ``cache_index``
+        # is the per-row logical write position (-1 = finished row, its
         # write is routed to the reserved trash page 0 and its keys are
         # fully masked via valid_len 0).
         assert T == 1, "paged cache entries are decode-only (T == 1)"
-        pt = cache["pages"]                       # [B, npp] int32
-        ps = cache["k"].shape[2]                  # page size
+        pt = cache["pages"][layer]                # [B, npp] int32
+        ps = cache["k"].shape[3]                  # page size
         npp = pt.shape[1]
         rows = jnp.arange(B)
         idx = cache_index
@@ -125,32 +127,43 @@ def attention_block(p, x, positions, cfg, *, window=None, softcap=None,
             safe = jnp.maximum(idx, 0)
             phys = jnp.where(idx < 0, 0, pt[rows, safe // ps])   # [B]
             off = safe % ps                                       # [B]
-            kc = cache["k"].at[phys, :, off].set(k[:, :, 0])
-            vc = cache["v"].at[phys, :, off].set(v[:, :, 0])
-        new_cache = {"k": kc, "v": vc, "pages": pt}
+            kc, vc = cache["k"], cache["v"]
+            # one in-place row write per batch row: a scatter at (layer,
+            # phys, :, off) makes CPU layout assignment transpose (and
+            # copy) the whole pool
+            for b in range(B):
+                at = (layer, phys[b], 0, off[b], 0)
+                kc = lax.dynamic_update_slice(
+                    kc, k[b, :, 0][None, None, :, None].astype(kc.dtype), at)
+                vc = lax.dynamic_update_slice(
+                    vc, v[b, :, 0][None, None, :, None].astype(vc.dtype), at)
+        new_cache = {"k": kc, "v": vc, "pages": cache["pages"]}
         # gather the slot's pages back into logical order: the dense
         # per-row view the masked attention below consumes
         with jax.named_scope("kv_gather"):
-            k = kc[pt].transpose(0, 2, 1, 3, 4).reshape(
+            k = kc[layer, pt].transpose(0, 2, 1, 3, 4).reshape(
                 B, hkv, npp * ps, dh)
-            v = vc[pt].transpose(0, 2, 1, 3, 4).reshape(
+            v = vc[layer, pt].transpose(0, 2, 1, 3, 4).reshape(
                 B, hkv, npp * ps, dh)
         valid_len = idx + T                       # [B]; -1 -> all masked
     elif cache is not None:
         # write this step's k/v at cache_index; keep the updated cache in
         # its sharded layout (a resharded DUS would replicate it)
         from repro.launch.partitioning import constrain as _con
+        lead = () if layer is None else (layer,)
+        spec = (None,) * len(lead) + ("batch", None, "seq_kv", None)
         with jax.named_scope("kv_write"):
-            kc = lax.dynamic_update_slice_in_dim(cache["k"], k,
-                                                 cache_index, 2)
-            vc = lax.dynamic_update_slice_in_dim(cache["v"], v,
-                                                 cache_index, 2)
-            kc = _con(kc, ("batch", None, "seq_kv", None))
-            vc = _con(vc, ("batch", None, "seq_kv", None))
+            at = lead + (0, 0, cache_index, 0)
+            kc = lax.dynamic_update_slice(
+                cache["k"], k.reshape((1,) * len(lead) + k.shape), at)
+            vc = lax.dynamic_update_slice(
+                cache["v"], v.reshape((1,) * len(lead) + v.shape), at)
+            kc = _con(kc, spec)
+            vc = _con(vc, spec)
         new_cache = {"k": kc, "v": vc}
         if T == 1:
             # decode: attend over the cache up to the current position
-            k, v = kc, vc
+            k, v = (kc, vc) if layer is None else (kc[layer], vc[layer])
             valid_len = cache_index + T
         # else prefill: the T tokens just computed ARE the valid keys —
         # attend over (k, v) directly with the static causal mask (keeps
@@ -390,8 +403,9 @@ def spec_ssm(cfg) -> Specs:
             "a_log": (None,), "skip": (None,), "w_out": (SSM_IN, EMBED)}
 
 
-def ssm_block(p, x, cfg, *, state=None, return_state=False):
-    """Mamba2 SSD block. state: [B, H, S, P] for decode (returns updated).
+def ssm_block(p, x, cfg, *, state=None, layer=None, return_state=False):
+    """Mamba2 SSD block. Decode: ``state`` is the layer-stacked
+    ``[R, B, H, S, P]`` state; returns it updated in place at ``layer``.
 
     ``return_state`` (prefill): also returns the final state, computed in
     closed form h_T = sum_s exp(cum_T - cum_s) b_s x_s^T (weights <= 1, so
@@ -421,12 +435,16 @@ def ssm_block(p, x, cfg, *, state=None, return_state=False):
     else:
         # single-step recurrence (T == 1)
         at = jnp.exp(a[:, 0]).astype(jnp.float32)              # [B, H]
-        st = state * at[..., None, None] + jnp.einsum(
+        st = lax.dynamic_index_in_dim(state, layer, 0, False)
+        st = st * at[..., None, None] + jnp.einsum(
             "bs,bhp->bhsp", b[:, 0].astype(jnp.float32),
             xin[:, 0].astype(jnp.float32))
+        new_state = lax.dynamic_update_index_in_dim(state, st, layer, 0)
+        # read the row back from the written state: the old state then
+        # has no reader after the write, so the write stays in place
+        st = lax.dynamic_index_in_dim(new_state, layer, 0, False)
         y = jnp.einsum("bs,bhsp->bhp", c[:, 0].astype(jnp.float32),
                        st)[:, None].astype(x.dtype)
-        new_state = st
     y = y + xin * p["skip"][None, None, :, None].astype(u.dtype)
     y = y.reshape(B, T, H * P) * jax.nn.silu(z)
     return dense(y, p["w_out"]), new_state

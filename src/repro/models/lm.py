@@ -152,8 +152,11 @@ def param_specs(cfg: ModelConfig) -> Any:
 # sublayer application
 # --------------------------------------------------------------------- #
 def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
-                cache_index=None, mode="train"):
-    """Returns (x, new_cache_entry, aux)."""
+                cache_index=None, layer=None, mode="train"):
+    """Returns (x, new_cache_entry, aux).
+
+    ``layer`` set (decode): ``cache`` is the slot's whole stacked
+    ``[R, ...]`` entry, read and written in place at that index."""
     aux = jnp.zeros((), jnp.float32)
     sp = ("batch", "seq", "embed")  # sequence-parallel residual layout
     if kind in ("attn", "local", "shared_attn"):
@@ -165,7 +168,7 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
             h, new_self = L.attention_block(
                 p["attn"], h, positions, cfg, window=window,
                 softcap=cfg.attn_softcap, causal=(mode != "encoder"),
-                cache=attn_cache, cache_index=cache_index)
+                cache=attn_cache, cache_index=cache_index, layer=layer)
             # reduce-scatter the row-parallel output into the SP layout
             x = x + constrain(h, sp)
             new_cross = None
@@ -174,7 +177,8 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 h = L.rms_norm(x, p["norm_x"])
                 if cache is not None and "cross" in cache:
                     # decode: attend to the prefilled cross k/v directly
-                    ck = cache["cross"]
+                    # (read-only: this layer's rows of the stacked cache)
+                    ck = {n: c[layer] for n, c in cache["cross"].items()}
                     B = x.shape[0]
                     q = L.dense(h, p["cross"]["wq"]).reshape(
                         B, x.shape[1], cfg.n_heads, cfg.hd
@@ -184,7 +188,7 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                                       use_pallas=cfg.use_pallas)
                     o = o.transpose(0, 2, 1, 3).reshape(B, x.shape[1], -1)
                     h = L.dense(o, p["cross"]["wo"])
-                    new_cross = ck
+                    new_cross = cache["cross"]
                 else:
                     h, _ = L.attention_block(p["cross"], h, positions, cfg,
                                              causal=False, memory=memory)
@@ -209,17 +213,19 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 h, new_state = L.ssm_block(p["ssm"], h, cfg, state=None,
                                            return_state=True)
                 new_cache = {"state": new_state}
+            elif cache is None:
+                h, _ = L.ssm_block(p["ssm"], h, cfg)
+                new_cache = None
             else:
-                state = cache.get("state") if cache else None
-                h, new_state = L.ssm_block(p["ssm"], h, cfg, state=state)
-                new_cache = ({"state": new_state} if cache is not None
-                             else None)
+                h, new_state = L.ssm_block(p["ssm"], h, cfg,
+                                           state=cache["state"], layer=layer)
+                new_cache = {"state": new_state}
             return x + constrain(h, sp), new_cache, aux
     raise ValueError(kind)
 
 
 def _unit(cfg, params, shared, x, positions, *, cache=None,
-          cache_index=None, mode="train"):
+          cache_index=None, layer=None, mode="train"):
     """Apply one repetition of the pattern. cache: dict slot->entry."""
     aux = jnp.zeros((), jnp.float32)
     new_cache = {} if cache is not None else None
@@ -227,7 +233,8 @@ def _unit(cfg, params, shared, x, positions, *, cache=None,
         p = shared if kind == "shared_attn" else params[name]
         c = cache.get(name) if cache is not None else None
         x, nc, a = _apply_slot(cfg, kind, p, x, positions, cache=c,
-                               cache_index=cache_index, mode=mode)
+                               cache_index=cache_index, layer=layer,
+                               mode=mode)
         aux = aux + a
         if new_cache is not None:
             new_cache[name] = nc
@@ -237,23 +244,29 @@ def _unit(cfg, params, shared, x, positions, *, cache=None,
 
 def _scan_units(cfg, params, x, positions, *, cache=None, cache_index=None,
                 mode="train"):
-    """lax.scan over the pattern repetitions, optional per-unit remat."""
+    """lax.scan over the pattern repetitions, optional per-unit remat.
+
+    A decode cache rides the scan's carry whole (``[R, ...]``) and layer
+    ``l`` writes its own rows in place at index ``l``: as the scan's
+    ``xs``/``ys`` it would be sliced per layer and stacked back into a
+    fresh array, rewriting (and XLA then copying) the whole cache every
+    step to change one row per layer."""
     shared = params.get("shared")
 
     def body(carry, xs):
-        x, aux = carry
-        blk, cache_sl = xs
-        x, new_c, a = _unit(cfg, blk, shared, x, positions, cache=cache_sl,
-                            cache_index=cache_index, mode=mode)
-        return (x, aux + a), new_c
+        x, aux, cache = carry
+        blk, layer = xs
+        x, cache, a = _unit(cfg, blk, shared, x, positions, cache=cache,
+                            cache_index=cache_index, layer=layer, mode=mode)
+        return (x, aux + a, cache), None
 
-    if cfg.remat == "block":
-        body = jax.checkpoint(body)
-    (x, aux), new_cache = lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)),
-        (params["blocks"], cache),
+    if cfg.remat == "block" and cache is None:
+        body = jax.checkpoint(body)      # training: no backward in decode
+    (x, aux, cache), _ = lax.scan(
+        body, (x, jnp.zeros((), jnp.float32), cache),
+        (params["blocks"], jnp.arange(cfg.repeats)),
         unroll=cfg.repeats if cfg.scan_unroll else 1)
-    return x, new_cache, aux
+    return x, cache, aux
 
 
 # --------------------------------------------------------------------- #
@@ -400,8 +413,8 @@ def init_paged_cache(cfg: ModelConfig, slots: int, n_pages: int,
 
     Attention k/v live in ONE physical page pool ``[R, P, Hkv, page,
     Dh]`` shared by every batch slot; ``pages`` ``[R, slots, npp]`` is
-    the per-slot page table (replicated over the scanned layer axis so
-    the whole pytree scans with ``lax.scan``; int32, ~nothing).
+    the per-slot page table (replicated over the layer axis, read by
+    layer ``l`` as ``pages[l]``; int32, ~nothing).
     Physical page 0 is reserved as the trash page — finished rows write
     there and the allocator never hands it out. SSM state is recurrent
     (no sequence axis), so it stays a per-slot row ``[R, slots, ...]``

@@ -1,13 +1,15 @@
 """Serving tests: the fixed host-loop oracle, the jit executable cache,
 paged KV slots, and DecodeEngine/ServeStream parity (DESIGN.md §13)."""
 
+import re
+
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
 
-from repro.configs import get_config, reduced
+from repro.configs import ARCHS, get_config, reduced
 from repro.core.schedule import EXEC_CACHE, ExecCache
 from repro.kernels.ops import attention
 from repro.models import lm
@@ -164,6 +166,32 @@ def test_paged_eviction_reuse_never_aliases_live_rows(gemma):
     _assert_parity(cfg, params, [ra, rb, rc], results)
 
 
+_HLO_TYPES = {"float32": "f32", "bfloat16": "bf16"}
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = (.+?) ([\w-]+)\(", re.M)
+
+
+@pytest.mark.parametrize("arch", ["granite_3_2b", "mamba2_1p3b",
+                                  "gemma2_2b"])
+def test_wave_never_copies_the_stacked_cache(arch):
+    """The decode cache rides the layer scan's carry and each layer
+    writes its rows in place: no ``copy`` or ``broadcast`` in the
+    compiled wave produces an array of a stacked cache's full shape (a
+    KV pool ``[R, P, Hkv, page, Dh]`` or SSM state ``[R, slots, ...]``).
+    """
+    cfg = reduced(get_config(arch))
+    params = lm.init_params(cfg, jax.random.PRNGKey(0))
+    eng = DecodeEngine(cfg, params, slots=3, page_size=4, max_ctx=16,
+                       max_new_cap=4)
+    shapes = {f"{_HLO_TYPES[a.dtype.name]}[{','.join(map(str, a.shape))}]"
+              for a in jax.tree.leaves(eng.st["cache"]) if a.ndim == 5}
+    hlo = eng._wave_fn.lower(params, eng.st,
+                             jnp.int32(2)).compile().as_text()
+    whole = [m.group(0).strip() for m in _HLO_INSTR.finditer(hlo)
+             if m.group(2) in ("copy", "broadcast")
+             and any(s in m.group(1) for s in shapes)]
+    assert shapes and not whole, whole
+
+
 # --------------------------------------------------------------------- #
 # engine parity vs the host-loop oracle
 # --------------------------------------------------------------------- #
@@ -213,6 +241,39 @@ def test_engine_parity_ssm_arch(mamba):
                        max_new_cap=6)
     results = ServeStream(eng, wave_len=3).run(reqs)
     _assert_parity(cfg, params, reqs, results)
+
+
+SERVED = [a for a in ARCHS if get_config(a).family != "encdec"
+          and not get_config(a).frontend]
+
+
+@pytest.mark.parametrize("arch", SERVED)
+def test_paged_decode_matches_prefill(arch):
+    """After every decode step each live row's carried logits equal
+    ``lm.prefill``'s last logits over the row's whole prefix (prompt
+    plus the tokens it emitted). Prefill keeps no decode cache, so this
+    checks the in-place paged writes and per-layer page gathers against
+    an independent forward pass; the ragged prompts cross page
+    boundaries at different steps."""
+    cfg = reduced(get_config(arch))
+    params = lm.init_params(cfg, jax.random.PRNGKey(2))
+    prompts = _prompts(cfg, [5, 6, 7], seed=16)
+    eng = DecodeEngine(cfg, params, slots=3, page_size=4, max_ctx=16,
+                       max_new_cap=4)
+    slots = [eng.admit(Request(prompt=p, max_new=4), handle=i)
+             for i, p in enumerate(prompts)]
+    last = jax.jit(lambda p, t: lm.prefill(cfg, p, {"tokens": t})[0][0, 0])
+    for _ in range(3):
+        eng.run_wave(1)
+        st = jax.device_get({k: eng.st[k]
+                             for k in ("buf", "emitted", "done", "logits")})
+        assert not st["done"][slots].any()
+        for s, p in zip(slots, prompts):
+            prefix = np.concatenate([p, st["buf"][s, :st["emitted"][s]]])
+            want = last(params, jnp.asarray(prefix[None]))
+            np.testing.assert_allclose(
+                st["logits"][s, :cfg.vocab],
+                np.asarray(want)[:cfg.vocab], rtol=1e-3, atol=1e-3)
 
 
 def test_engine_wave_length_invariance(gemma):

@@ -12,7 +12,9 @@ module is imported: only one process at a time may load the TPU
 library, and the tests of this file run in the process that loads it.
 """
 
+import functools
 import os
+import re
 
 import pytest
 
@@ -132,3 +134,68 @@ def test_flash_attention_compiles_for_v5e(one_chip):
         one_chip,
         lambda q, k, v: flash_attention(q, k, v, softcap=50.0,
                                         interpret=False), q, kv, kv)
+
+
+def _pool_copies(hlo: str, shape: str):
+    """``(in_entry, instruction, result type, operand type)`` for every
+    ``copy`` or ``broadcast`` in ``hlo`` whose result has type
+    ``shape`` (types with their layouts)."""
+    types, out, entry = {}, [], False
+    for line in hlo.splitlines():
+        if line.endswith("{") and not line.startswith(" "):
+            types, entry = {}, line.startswith("ENTRY")
+            continue
+        m = re.match(r"\s*(?:ROOT\s+)?(%\S+) = (\S+) ([\w-]+)\((%[^,)]+)?",
+                     line)
+        if not m:
+            continue
+        types[m.group(1)] = m.group(2)
+        if m.group(3) in ("copy", "broadcast") and \
+                m.group(2).startswith(shape + "{"):
+            out.append((entry, line.strip()[:160], m.group(2),
+                        types.get(m.group(4) or "")))
+    return out
+
+
+def test_granite_wave_writes_the_kv_pool_in_place(one_chip):
+    """granite-3-2b's serving wave at full width, 3 slots x 4,096
+    positions in pages of 16: no decode step copies the stacked KV pool
+    ``bf16[40,769,8,16,64]``. The only pool copies are at the
+    executable's boundary, one into and one out of the loop's layout per
+    pool: relayouts from the device's default layout (pages in lanes)."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.runtime.serve import DecodeEngine
+
+    cfg = get_config("granite_3_2b")
+    waves = []
+
+    def state():
+        # the engine under eval_shape: shapes only, nothing allocated
+        eng = DecodeEngine(cfg, None, slots=3, page_size=16, max_ctx=4096,
+                           max_new_cap=512)
+        waves.append(eng._wave_fn)
+        return eng.st
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    st = jax.eval_shape(state)
+    params = jax.eval_shape(functools.partial(lm.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    assert st["cache"]["0_attn"]["self"]["k"].shape == (40, 769, 8, 16, 64)
+    compiled = waves[0].lower(
+        on_chip(params), on_chip(st),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    mem = compiled.memory_analysis()
+    print("granite wave, 3 x 4096: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes, "peak",
+          mem.argument_size_in_bytes + mem.output_size_in_bytes
+          - mem.alias_size_in_bytes + mem.temp_size_in_bytes)
+    copies = _pool_copies(compiled.as_text(), "bf16[40,769,8,16,64]")
+    assert all(entry for entry, _, _, _ in copies), copies
+    assert len(copies) <= 4, copies
+    for _, line, typ, src in copies:
+        assert " copy(" in line and src is not None, line
+        assert typ != src, f"a pool copy that keeps its layout: {line}"
