@@ -32,7 +32,9 @@ class ModelConfig:
     # layer stacking: `pattern` is the repeating unit of sublayer kinds
     #   'attn'        causal (optionally windowed) attention + MLP/MoE
     #   'local'       sliding-window attention + MLP (gemma2 alternation)
-    #   'ssm'         Mamba2 SSD block
+    #   'ssm'         simplified SSD block (no conv, no gated norm)
+    #   'mamba2'      the published Mamba2 block: fused input projection,
+    #                 depthwise causal conv, SSD, gated RMSNorm
     #   'shared_attn' attention block with weights SHARED across repeats
     pattern: tuple = ("attn",)
     rope_theta: float = 1e4
@@ -52,6 +54,10 @@ class ModelConfig:
     ssm_state: int = 0
     ssm_heads: int = 0
     ssm_d_inner: int = 0
+    ssm_conv: int = 4                   # 'mamba2': causal conv width
+    ssm_groups: int = 1                 # 'mamba2': B/C groups (ngroups)
+    residual_in_fp32: bool = False      # residual stream kept in f32
+    norm_eps: float = 1e-6              # every RMSNorm's epsilon
     # enc-dec
     n_enc_layers: int = 0
     # modality frontend stub
@@ -114,8 +120,13 @@ class ModelConfig:
             mlp = 3 * d * f * e + d * self.n_experts  # experts + router
         di, H, S = self.ssm_d_inner, self.ssm_heads, self.ssm_state
         ssm = 2 * d * di + d * 2 * S + d * H + di * d  # B/C group-shared
+        conv = di + 2 * self.ssm_groups * S
+        mamba2 = (d * (di + conv + H) + di * d       # in_proj, out_proj
+                  + (self.ssm_conv + 1) * conv      # conv weight + bias
+                  + 3 * H + di)                     # dt_bias, A, D, norm
         per = {"attn": attn + mlp, "local": attn + mlp,
-               "shared_attn": attn + mlp, "ssm": ssm + d}
+               "shared_attn": attn + mlp, "ssm": ssm + d,
+               "mamba2": mamba2 + d}
         reps = self.repeats
         total = 0
         for kind in self.pattern:

@@ -1,9 +1,12 @@
 """mamba2-1.3b [ssm]: 48L d_model=2048 attention-free vocab=50280,
 ssm_state=128 — SSD (state-space duality) [arXiv:2405.21060; unverified].
 
-The depthwise conv1d of the reference implementation is omitted
-(DESIGN.md §8); the SSD core (the paper's contribution and the compute
-hot-spot) is kernels/ssd_scan.py.
+``pattern=("ssm",)`` is the simplified SSD block: no depthwise conv1d,
+no dt bias, no gated RMSNorm, D applied to ``x * dt``. The published
+Mamba2 block is the ``mamba2`` kind (DESIGN.md §18): the benchmark's
+``mamba2-1.3b`` configuration selects it by ``pattern`` from this
+config. The SSD core (the compute hot-spot) is kernels/ssd_scan.py on
+the Pallas lane and ``kernels/ref.ssd_chunked`` on the XLA lane.
 """
 
 from repro.configs import ModelConfig

@@ -448,3 +448,111 @@ def ssm_block(p, x, cfg, *, state=None, layer=None, return_state=False):
     y = y + xin * p["skip"][None, None, :, None].astype(u.dtype)
     y = y.reshape(B, T, H * P) * jax.nn.silu(z)
     return dense(y, p["w_out"]), new_state
+
+
+# --------------------------------------------------------------------- #
+# the published Mamba2 block (mamba_ssm's ``Mamba2``, arXiv:2405.21060 §7)
+# --------------------------------------------------------------------- #
+def init_mamba2(key, cfg) -> Params:
+    """One fused input projection ``[z | xBC | dt]``, a depthwise causal
+    conv over ``xBC`` (with bias), per-head ``dt_bias``, ``A = -exp(a_log)``
+    and ``D``, the gated RMSNorm's scale (``1 + norm``) and ``out_proj``."""
+    d, di, H = cfg.d_model, cfg.ssm_d_inner, cfg.ssm_heads
+    conv = di + 2 * cfg.ssm_groups * cfg.ssm_state
+    K = cfg.ssm_conv
+    ks = jax.random.split(key, 4)
+    lim = K ** -0.5
+    return {
+        "w_in": jax.random.normal(ks[0], (d, di + conv + H), cfg.dtype)
+        * d ** -0.5,
+        "conv_w": jax.random.uniform(ks[1], (K, conv), cfg.dtype, -lim, lim),
+        "conv_b": jax.random.uniform(ks[2], (conv,), cfg.dtype, -lim, lim),
+        "dt_bias": jnp.zeros((H,), jnp.float32),
+        "a_log": jnp.zeros((H,), jnp.float32),
+        "d_skip": jnp.ones((H,), jnp.float32),
+        "norm": jnp.zeros((di,), jnp.float32),
+        "w_out": jax.random.normal(ks[3], (di, d), cfg.dtype) * di ** -0.5,
+    }
+
+
+def spec_mamba2(cfg) -> Specs:
+    return {"w_in": (EMBED, None), "conv_w": (None, None),
+            "conv_b": (None,), "dt_bias": (None,), "a_log": (None,),
+            "d_skip": (None,), "norm": (None,), "w_out": (SSM_IN, EMBED)}
+
+
+def mamba2_block(p, x, cfg, *, cache=None, layer=None, return_state=False):
+    """The Mamba2 mixer on normed ``x [B, T, D]``.
+
+    Decode (``cache`` set, T == 1): ``cache`` holds the layer-stacked
+    f32 SSM state ``[R, B, H, P, S]`` and conv window ``[R, B, K-1, C]``
+    (the last K-1 pre-conv ``xBC`` rows); both are written in place at
+    ``layer`` and returned whole. ``return_state`` (prefill): also
+    returns this layer's final state and conv window (left-padded with
+    zeros for prompts shorter than K-1)."""
+    B, T, _ = x.shape
+    H, S, G, K = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_groups, cfg.ssm_conv
+    di = cfg.ssm_d_inner
+    P = di // H
+    f32 = jnp.float32
+    z, xbc, dt = jnp.split(dense(x, p["w_in"]), [di, 2 * di + 2 * G * S],
+                           axis=-1)
+    new_cache = None
+    with jax.named_scope("conv"):
+        w = p["conv_w"].astype(f32)
+        if cache is None:
+            pad = jnp.pad(xbc, ((0, 0), (K - 1, 0), (0, 0)))
+            xc = sum(pad[:, k:k + T].astype(f32) * w[k] for k in range(K))
+            window = pad[:, T:]
+        else:
+            conv = cache["conv"]
+            win = lax.dynamic_index_in_dim(conv, layer, 0, False)
+            full = jnp.concatenate([win, xbc.astype(conv.dtype)], axis=1)
+            conv = lax.dynamic_update_index_in_dim(conv, full[:, 1:], layer,
+                                                   0)
+            xc = jnp.sum(full.astype(f32) * w, axis=1, keepdims=True)
+        xc = jax.nn.silu(xc + p["conv_b"].astype(f32))
+    xs, b, c = jnp.split(xc, [di, di + G * S], axis=-1)
+    xs = xs.reshape(B, T, G, H // G, P)
+    b, c = b.reshape(B, T, G, S), c.reshape(B, T, G, S)
+    dt = jax.nn.softplus(dt.astype(f32) + p["dt_bias"])           # [B,T,H]
+    a = -jnp.exp(p["a_log"]) * dt                                  # log-decay
+    xin = xs * dt.reshape(B, T, G, H // G, 1)
+    with jax.named_scope("ssd"):
+        if cache is None:
+            if G == 1:
+                bb, cc = b[:, :, 0], c[:, :, 0]                     # [B,T,S]
+            else:
+                bb, cc = (jnp.repeat(v, H // G, axis=2) for v in (b, c))
+            y = ops.ssd(xin.reshape(B, T, H, P), a, bb, cc,
+                        use_pallas=cfg.use_pallas, chunk=cfg.ssm_chunk,
+                        unroll=cfg.scan_unroll)
+            y = y.reshape(B, T, G, H // G, P)
+            if return_state:
+                cum = jnp.cumsum(a, axis=1)
+                wt = jnp.exp(cum[:, -1:] - cum).reshape(B, T, G, H // G)
+                st = jnp.einsum("btgh,btgs,btghp->bghps", wt, b, xin)
+                new_cache = {"state": st.reshape(B, H, P, S),
+                             "conv": window}
+        else:
+            state = cache["state"]
+            st = lax.dynamic_index_in_dim(state, layer, 0, False)
+            st = st.reshape(B, G, H // G, P, S) \
+                * jnp.exp(a[:, 0]).reshape(B, G, H // G, 1, 1) \
+                + jnp.einsum("bgs,bghp->bghps", b[:, 0], xin[:, 0])
+            state = lax.dynamic_update_index_in_dim(
+                state, st.reshape(B, H, P, S), layer, 0)
+            # read the row back from the written state, as ssm_block does:
+            # the old state then has no reader after the write
+            st = lax.dynamic_index_in_dim(state, layer, 0, False)
+            y = jnp.einsum("bgs,bghps->bghp", c[:, 0],
+                           st.reshape(B, G, H // G, P, S))[:, None]
+            new_cache = {"state": state, "conv": conv}
+    y = y + xs * p["d_skip"].reshape(G, H // G, 1)
+    with jax.named_scope("gate_norm"):
+        g = y.reshape(B, T, di) * jax.nn.silu(z.astype(f32))
+        g = g.reshape(B, T, G, di // G)
+        g = g * lax.rsqrt(jnp.mean(g * g, axis=-1, keepdims=True)
+                          + cfg.norm_eps)
+        g = (g.reshape(B, T, di) * (1.0 + p["norm"])).astype(x.dtype)
+    return dense(g, p["w_out"]), new_cache

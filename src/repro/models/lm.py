@@ -61,6 +61,8 @@ def _init_slot(key, cfg, kind: str) -> Params:
         return p
     if kind == "ssm":
         return {"norm": z, "ssm": L.init_ssm(key, cfg)}
+    if kind == "mamba2":
+        return {"norm": z, "mixer": L.init_mamba2(key, cfg)}
     raise ValueError(f"unknown sublayer kind {kind!r}")
 
 
@@ -78,6 +80,8 @@ def _spec_slot(cfg, kind: str) -> Any:
         return p
     if kind == "ssm":
         return {"norm": (None,), "ssm": L.spec_ssm(cfg)}
+    if kind == "mamba2":
+        return {"norm": (None,), "mixer": L.spec_mamba2(cfg)}
     raise ValueError(kind)
 
 
@@ -151,6 +155,12 @@ def param_specs(cfg: ModelConfig) -> Any:
 # --------------------------------------------------------------------- #
 # sublayer application
 # --------------------------------------------------------------------- #
+def _norm(cfg, x, scale):
+    """RMSNorm at the config's epsilon, in the compute dtype (the
+    residual stream ``x`` may be f32 under ``residual_in_fp32``)."""
+    return L.rms_norm(x, scale, cfg.norm_eps).astype(cfg.jdtype)
+
+
 def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 cache_index=None, layer=None, mode="train"):
     """Returns (x, new_cache_entry, aux).
@@ -163,7 +173,7 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
         window = cfg.local_window if kind == "local" else cfg.window
         # named scopes: a profile splits the step's device time by them
         with jax.named_scope("attn"):
-            h = L.rms_norm(x, p["norm1"])
+            h = _norm(cfg, x, p["norm1"])
             attn_cache = cache.get("self") if cache else None
             h, new_self = L.attention_block(
                 p["attn"], h, positions, cfg, window=window,
@@ -174,7 +184,7 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
             new_cross = None
             if (cfg.family == "encdec" and kind == "attn"
                     and mode != "encoder"):
-                h = L.rms_norm(x, p["norm_x"])
+                h = _norm(cfg, x, p["norm_x"])
                 if cache is not None and "cross" in cache:
                     # decode: attend to the prefilled cross k/v directly
                     # (read-only: this layer's rows of the stacked cache)
@@ -194,7 +204,7 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                                              causal=False, memory=memory)
                 x = x + constrain(h, sp)
         with jax.named_scope("mlp"):
-            h = L.rms_norm(x, p["norm2"])
+            h = _norm(cfg, x, p["norm2"])
             if cfg.n_experts:
                 h, aux = L.moe_block(p["moe"], h, cfg)
             else:
@@ -208,7 +218,7 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
         return x, new_cache, aux
     if kind == "ssm":
         with jax.named_scope("ssm"):
-            h = L.rms_norm(x, p["norm"])
+            h = _norm(cfg, x, p["norm"])
             if mode == "prefill":
                 h, new_state = L.ssm_block(p["ssm"], h, cfg, state=None,
                                            return_state=True)
@@ -220,6 +230,13 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 h, new_state = L.ssm_block(p["ssm"], h, cfg,
                                            state=cache["state"], layer=layer)
                 new_cache = {"state": new_state}
+            return x + constrain(h, sp), new_cache, aux
+    if kind == "mamba2":
+        # scopes inside: conv, ssd, gate_norm (layers.mamba2_block)
+        with jax.named_scope("ssm"):
+            h, new_cache = L.mamba2_block(
+                p["mixer"], _norm(cfg, x, p["norm"]), cfg, cache=cache,
+                layer=layer, return_state=(mode == "prefill"))
             return x + constrain(h, sp), new_cache, aux
     raise ValueError(kind)
 
@@ -281,8 +298,13 @@ def _embed(cfg, params, batch):
         patches = L.dense(batch["patches"], params["front"]["w"])
         pl_ = patches.shape[1]
         x = jnp.concatenate([patches.astype(x.dtype), x[:, pl_:]], axis=1)
-    x = constrain(x, ("batch", "seq", "embed"))
+    x = constrain(_residual(cfg, x), ("batch", "seq", "embed"))
     return x
+
+
+def _residual(cfg, x):
+    """The residual stream's dtype: f32 under ``residual_in_fp32``."""
+    return x.astype(jnp.float32) if cfg.residual_in_fp32 else x
 
 
 def _encoder(cfg, params, frames):
@@ -302,7 +324,7 @@ def _encoder(cfg, params, frames):
     (x, _), _ = lax.scan(bodyf, (x, jnp.zeros(())),
                          params["enc"]["blocks"],
                          unroll=cfg.n_enc_layers if cfg.scan_unroll else 1)
-    return L.rms_norm(x, params["enc"]["norm"])
+    return L.rms_norm(x, params["enc"]["norm"], cfg.norm_eps)
 
 
 def _logits(cfg, params, x):
@@ -379,7 +401,7 @@ def train_loss(cfg: ModelConfig, params, batch):
         x = _embed(cfg, params, batch)
         positions = jnp.arange(x.shape[1])
         x, _, aux = _scan_units(cfg, params, x, positions, mode="train")
-    x = L.rms_norm(x, params["norm_f"])
+    x = _norm(cfg, x, params["norm_f"])
     loss = _chunked_loss(cfg, params, x, batch["labels"])
     if cfg.n_experts:
         loss = loss + 0.01 * aux / cfg.n_layers
@@ -400,11 +422,24 @@ def init_cache(cfg: ModelConfig, B: int, T: int):
                     "k": jnp.zeros((R, B, hkv, T, hd), cfg.jdtype),
                     "v": jnp.zeros((R, B, hkv, T, hd), cfg.jdtype)}
             cache[name] = ent
-        elif kind == "ssm":
-            P = cfg.ssm_d_inner // cfg.ssm_heads
-            cache[name] = {"state": jnp.zeros(
-                (R, B, cfg.ssm_heads, cfg.ssm_state, P), jnp.float32)}
+        elif kind in ("ssm", "mamba2"):
+            cache[name] = _recurrent_state(cfg, kind, R, B)
     return cache
+
+
+def _recurrent_state(cfg, kind, R, B):
+    """Zeroed recurrent state of ``B`` rows: the f32 SSM state, and for
+    ``mamba2`` the conv window of the last ``ssm_conv - 1`` pre-conv
+    ``xBC`` rows (DESIGN.md §18)."""
+    H, S, P = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_d_inner // cfg.ssm_heads
+    # mamba2 keeps the state dim minor: [.., P, S] fills the chip's
+    # 128-lane tiles where [.., S, P] pads P = 64 to 128
+    shape = (R, B, H, S, P) if kind == "ssm" else (R, B, H, P, S)
+    ent = {"state": jnp.zeros(shape, jnp.float32)}
+    if kind == "mamba2":
+        conv = cfg.ssm_d_inner + 2 * cfg.ssm_groups * cfg.ssm_state
+        ent["conv"] = jnp.zeros((R, B, cfg.ssm_conv - 1, conv), cfg.jdtype)
+    return ent
 
 
 def init_paged_cache(cfg: ModelConfig, slots: int, n_pages: int,
@@ -416,9 +451,10 @@ def init_paged_cache(cfg: ModelConfig, slots: int, n_pages: int,
     the per-slot page table (replicated over the layer axis, read by
     layer ``l`` as ``pages[l]``; int32, ~nothing).
     Physical page 0 is reserved as the trash page — finished rows write
-    there and the allocator never hands it out. SSM state is recurrent
-    (no sequence axis), so it stays a per-slot row ``[R, slots, ...]``
-    and is simply overwritten at admission.
+    there and the allocator never hands it out. SSM state (and the
+    ``mamba2`` conv window beside it) is recurrent (no sequence axis), so
+    it stays a per-slot row ``[R, slots, ...]`` and is simply overwritten
+    at admission.
     """
     if cfg.family == "encdec":
         raise NotImplementedError(
@@ -438,10 +474,8 @@ def init_paged_cache(cfg: ModelConfig, slots: int, n_pages: int,
                                cfg.jdtype),
                 "pages": jnp.zeros((R, slots, pages_per_slot),
                                    jnp.int32)}}
-        elif kind == "ssm":
-            P = cfg.ssm_d_inner // cfg.ssm_heads
-            cache[name] = {"state": jnp.zeros(
-                (R, slots, cfg.ssm_heads, cfg.ssm_state, P), jnp.float32)}
+        elif kind in ("ssm", "mamba2"):
+            cache[name] = _recurrent_state(cfg, kind, R, slots)
     return cache
 
 
@@ -472,10 +506,9 @@ def admit_prefill(cfg: ModelConfig, paged, prefill_cache, pages, slot):
                 out[key] = ent[key].at[:, pages[:npg]].set(blocks)
             out["pages"] = ent["pages"].at[:, slot].set(pages)
             new[name] = {"self": out}
-        elif kind == "ssm":
-            st = paged[name]["state"].at[:, slot].set(
-                prefill_cache[name]["state"][:, 0])
-            new[name] = {"state": st}
+        elif kind in ("ssm", "mamba2"):
+            new[name] = {k: v.at[:, slot].set(prefill_cache[name][k][:, 0])
+                         for k, v in paged[name].items()}
     return new
 
 
@@ -492,9 +525,11 @@ def cache_specs(cfg: ModelConfig):
             if cfg.family == "encdec" and kind == "attn":
                 ent["cross"] = dict(kv)
             spec[name] = ent
-        elif kind == "ssm":
+        elif kind in ("ssm", "mamba2"):
             spec[name] = {"state": (None, "batch", "ssm_heads", None,
                                     None)}
+            if kind == "mamba2":
+                spec[name]["conv"] = (None, "batch", None, None)
     return spec
 
 
@@ -513,7 +548,7 @@ def prefill(cfg: ModelConfig, params, batch, max_len: int | None = None):
         # fill cross k/v once per layer below via _apply_slot(memory=...)
     x, new_cache, _ = _prefill_scan(cfg, params, x, positions, cache,
                                     memory)
-    x = L.rms_norm(x, params["norm_f"])
+    x = _norm(cfg, x, params["norm_f"])
     logits = _logits(cfg, params, x[:, -1:])
     return logits, new_cache
 
@@ -566,6 +601,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, cache_index):
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.scale_embed:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    x = _residual(cfg, x)
     B = x.shape[0]
     ci = jnp.asarray(cache_index, jnp.int32)
     if ci.ndim == 1:
@@ -575,7 +611,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, cache_index):
     x, new_cache, _ = _scan_units(cfg, params, x, positions, cache=cache,
                                   cache_index=ci, mode="decode")
     with jax.named_scope("head"):
-        x = L.rms_norm(x, params["norm_f"])
+        x = _norm(cfg, x, params["norm_f"])
         return _logits(cfg, params, x), new_cache
 
 

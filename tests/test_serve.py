@@ -29,6 +29,22 @@ def mamba():
     return cfg, lm.init_params(cfg, jax.random.PRNGKey(0))
 
 
+def _config(arch):
+    """A reduced registry config; ``"mamba2"`` is mamba2-1.3b on the
+    published Mamba2 block (conv window beside the SSM state, gated
+    RMSNorm, f32 residual), chosen by ``pattern``."""
+    if arch == "mamba2":
+        return reduced(get_config("mamba2_1p3b")).replace(
+            pattern=("mamba2",), residual_in_fp32=True, norm_eps=1e-5)
+    return reduced(get_config(arch))
+
+
+@pytest.fixture(scope="module")
+def mamba2():
+    cfg = _config("mamba2")
+    return cfg, lm.init_params(cfg, jax.random.PRNGKey(0))
+
+
 def _prompts(cfg, lens, seed=0):
     rng = np.random.default_rng(seed)
     return [rng.integers(0, cfg.vocab, (t,)).astype(np.int32)
@@ -171,19 +187,20 @@ _HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = (.+?) ([\w-]+)\(", re.M)
 
 
 @pytest.mark.parametrize("arch", ["granite_3_2b", "mamba2_1p3b",
-                                  "gemma2_2b"])
+                                  "gemma2_2b", "mamba2"])
 def test_wave_never_copies_the_stacked_cache(arch):
     """The decode cache rides the layer scan's carry and each layer
     writes its rows in place: no ``copy`` or ``broadcast`` in the
     compiled wave produces an array of a stacked cache's full shape (a
-    KV pool ``[R, P, Hkv, page, Dh]`` or SSM state ``[R, slots, ...]``).
+    KV pool ``[R, P, Hkv, page, Dh]``, SSM state ``[R, slots, ...]`` or
+    conv window ``[R, slots, K-1, C]``).
     """
-    cfg = reduced(get_config(arch))
+    cfg = _config(arch)
     params = lm.init_params(cfg, jax.random.PRNGKey(0))
     eng = DecodeEngine(cfg, params, slots=3, page_size=4, max_ctx=16,
                        max_new_cap=4)
     shapes = {f"{_HLO_TYPES[a.dtype.name]}[{','.join(map(str, a.shape))}]"
-              for a in jax.tree.leaves(eng.st["cache"]) if a.ndim == 5}
+              for a in jax.tree.leaves(eng.st["cache"]) if a.ndim >= 4}
     hlo = eng._wave_fn.lower(params, eng.st,
                              jnp.int32(2)).compile().as_text()
     whole = [m.group(0).strip() for m in _HLO_INSTR.finditer(hlo)
@@ -247,7 +264,7 @@ SERVED = [a for a in ARCHS if get_config(a).family != "encdec"
           and not get_config(a).frontend]
 
 
-@pytest.mark.parametrize("arch", SERVED)
+@pytest.mark.parametrize("arch", SERVED + ["mamba2"])
 def test_paged_decode_matches_prefill(arch):
     """After every decode step each live row's carried logits equal
     ``lm.prefill``'s last logits over the row's whole prefix (prompt
@@ -255,7 +272,7 @@ def test_paged_decode_matches_prefill(arch):
     checks the in-place paged writes and per-layer page gathers against
     an independent forward pass; the ragged prompts cross page
     boundaries at different steps."""
-    cfg = reduced(get_config(arch))
+    cfg = _config(arch)
     params = lm.init_params(cfg, jax.random.PRNGKey(2))
     prompts = _prompts(cfg, [5, 6, 7], seed=16)
     eng = DecodeEngine(cfg, params, slots=3, page_size=4, max_ctx=16,
@@ -274,6 +291,54 @@ def test_paged_decode_matches_prefill(arch):
             np.testing.assert_allclose(
                 st["logits"][s, :cfg.vocab],
                 np.asarray(want)[:cfg.vocab], rtol=1e-3, atol=1e-3)
+
+
+def test_rollback_restores_the_conv_window(mamba2):
+    """A rolled-back wave leaves the SSM state and the conv window
+    bitwise as they were at the wave's boundary."""
+    cfg, params = mamba2
+    eng = DecodeEngine(cfg, params, slots=2, page_size=4, max_ctx=16,
+                       max_new_cap=8)
+    for i, p in enumerate(_prompts(cfg, [2, 6], seed=21)):
+        eng.admit(Request(prompt=p, max_new=8), handle=i)
+    eng.wave(2)
+    before = jax.device_get(eng.st["cache"]["0_mamba2"])
+    eng.run_wave(2)
+    moved = jax.device_get(eng.st["cache"]["0_mamba2"])
+    assert not np.array_equal(moved["conv"], before["conv"])
+    eng.rollback()
+    after = jax.device_get(eng.st["cache"]["0_mamba2"])
+    for k in ("state", "conv"):
+        assert np.array_equal(after[k], before[k]), k
+
+
+def test_reused_slot_starts_fresh(mamba2):
+    """A slot reused after an eviction gives a fresh engine's logits and
+    tokens: admission overwrites both recurrent states, so nothing of the
+    evicted request's SSM state or conv window leaks into the next."""
+    cfg, params = mamba2
+    first, second = _prompts(cfg, [7, 2], seed=22)
+
+    def engine():
+        return DecodeEngine(cfg, params, slots=1, page_size=4, max_ctx=16,
+                            max_new_cap=6)
+
+    def serve(eng):
+        slot = eng.admit(Request(prompt=second, max_new=6))
+        seen = [np.asarray(eng.st["logits"][slot])]
+        for _ in range(3):
+            eng.run_wave(1)
+            seen.append(np.asarray(eng.st["logits"][slot]))
+            eng.commit_wave()
+        return np.stack(seen), np.asarray(eng.st["buf"][slot])
+
+    used = engine()
+    slot = used.admit(Request(prompt=first, max_new=6))
+    used.wave(3)
+    used.evict(slot)
+    got, fresh = serve(used), serve(engine())
+    assert np.array_equal(got[0], fresh[0])
+    assert np.array_equal(got[1], fresh[1])
 
 
 def test_engine_wave_length_invariance(gemma):

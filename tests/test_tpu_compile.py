@@ -199,3 +199,51 @@ def test_granite_wave_writes_the_kv_pool_in_place(one_chip):
     for _, line, typ, src in copies:
         assert " copy(" in line and src is not None, line
         assert typ != src, f"a pool copy that keeps its layout: {line}"
+
+
+def test_mamba2_wave_writes_both_states_in_place(one_chip):
+    """mamba2-1.3b's serving wave on the published Mamba2 block at full
+    width, 16 slots x 768 positions: no decode step copies the stacked
+    f32 SSM state ``f32[48,16,64,64,128]`` or conv window
+    ``bf16[48,16,3,4352]``. The state is not copied at all; the conv
+    window (20 MB) is relaid out once into and once out of the loop, at
+    the executable's boundary."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.runtime.serve import DecodeEngine
+
+    cfg = get_config("mamba2_1p3b").replace(
+        pattern=("mamba2",), vocab=50277, tie_embeddings=True,
+        residual_in_fp32=True, norm_eps=1e-5)
+    waves = []
+
+    def state():
+        eng = DecodeEngine(cfg, None, slots=16, page_size=16, max_ctx=768,
+                           max_new_cap=256)
+        waves.append(eng._wave_fn)
+        return eng.st
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    st = jax.eval_shape(state)
+    params = jax.eval_shape(functools.partial(lm.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    ent = st["cache"]["0_mamba2"]
+    assert ent["state"].shape == (48, 16, 64, 64, 128)
+    assert ent["conv"].shape == (48, 16, 3, 4352)
+    compiled = waves[0].lower(
+        on_chip(params), on_chip(st),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    mem = compiled.memory_analysis()
+    print("mamba2 wave, 16 x 768: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    hlo = compiled.as_text()
+    assert not _pool_copies(hlo, "f32[48,16,64,64,128]")
+    copies = _pool_copies(hlo, "bf16[48,16,3,4352]")
+    assert all(entry for entry, _, _, _ in copies), copies
+    assert len(copies) <= 2, copies
+    for _, line, typ, src in copies:
+        assert " copy(" in line and src is not None, line
+        assert typ != src, f"a window copy that keeps its layout: {line}"
