@@ -9,7 +9,8 @@ from . import ops, ref
 from .aggregate import aggregate
 from .flash_attention import flash_attention
 from .ssd_scan import ssd_scan
+from .ssm_step import mamba2_state_step
 from .xor_code import xor_encode, xor_fold, xor_decode
 
 __all__ = ["ops", "ref", "aggregate", "flash_attention", "ssd_scan",
-           "xor_encode", "xor_fold", "xor_decode"]
+           "mamba2_state_step", "xor_encode", "xor_fold", "xor_decode"]

@@ -11,15 +11,20 @@ against the ref oracles.
 
 from __future__ import annotations
 
+import functools
+
 import jax.numpy as jnp
+from jax import lax
 
 from . import ref
 from .aggregate import aggregate as aggregate_pallas
 from .flash_attention import flash_attention as flash_attention_pallas
 from .ssd_scan import ssd_scan as ssd_scan_pallas
+from .ssm_step import mamba2_state_step
 from .xor_code import xor_encode as xor_encode_pallas
 
-__all__ = ["attention", "ssd", "combine_aggregates", "xor_fold"]
+__all__ = ["attention", "ssd", "ssm_state_step", "combine_aggregates",
+           "xor_fold"]
 
 
 _CHUNK_THRESHOLD = 2 ** 21  # Tq*Tk above which the XLA path chunks
@@ -67,6 +72,20 @@ def ssd(x, a, b, c, *, use_pallas=False, chunk=256, unroll=False):
     if b.ndim == 4:  # per-head inputs: fall back to the oracle
         return ref.ssd_scan_ref(x, a, b, c)
     return ref.ssd_chunked(x, a, b, c, chunk=chunk, unroll=unroll)
+
+
+def ssm_state_step(state, layer, da, xin, b, c):
+    """One Mamba2 decode step on layer ``layer`` of the stacked state
+    (``ref.ssm_state_step_ref`` has the shapes): ``(state, y)``.
+
+    Chosen by the platform the step is lowered for, not by
+    ``use_pallas``: on a TPU the fused kernel reads and writes the layer's
+    state once; elsewhere the XLA form, which reads it a second time for
+    ``y`` to keep its write in place."""
+    return lax.platform_dependent(
+        state, layer, da, xin, b, c,
+        tpu=functools.partial(mamba2_state_step, interpret=False),
+        default=ref.ssm_state_step_ref)
 
 
 def combine_aggregates(values, segment_ids, num_segments, *,

@@ -302,3 +302,26 @@ def ssd_scan_ref(x: jnp.ndarray, a: jnp.ndarray, b: jnp.ndarray,
           jnp.moveaxis(c, 1, 0).astype(jnp.float32))
     _, ys = lax.scan(step, h0, xs)
     return jnp.moveaxis(ys, 0, 1).astype(x.dtype)
+
+
+def ssm_state_step_ref(state: jnp.ndarray, layer, da: jnp.ndarray,
+                       xin: jnp.ndarray, b: jnp.ndarray, c: jnp.ndarray):
+    """One Mamba2 decode step on layer ``layer`` of the stacked state
+    ``f32[R, B, H, P, S]``, in XLA: ``h' = da * h + xin (x) b`` written in
+    place, ``y = sum_S c * h'``. ``da: [B, H]``, ``xin: [B, H, P]``,
+    ``b``, ``c: [B, G, S]`` (head ``h`` reads group ``h // (H // G)``).
+    Returns ``(state, y: [B, H, P])``; the oracle of
+    ``ssm_step.mamba2_state_step``."""
+    B, H, P, S = state.shape[1:]
+    G = b.shape[1]
+    st = lax.dynamic_index_in_dim(state, layer, 0, False)
+    st = st.reshape(B, G, H // G, P, S) * da.reshape(B, G, H // G, 1, 1) \
+        + jnp.einsum("bgs,bghp->bghps", b, xin.reshape(B, G, H // G, P))
+    state = lax.dynamic_update_index_in_dim(
+        state, st.reshape(B, H, P, S), layer, 0)
+    # read the row back from the written state: the old state then has no
+    # reader after the write, so XLA keeps the write in place (at the
+    # price of reading the layer's state a second time)
+    st = lax.dynamic_index_in_dim(state, layer, 0, False)
+    y = jnp.einsum("bgs,bghps->bghp", c, st.reshape(B, G, H // G, P, S))
+    return state, y.reshape(B, H, P)

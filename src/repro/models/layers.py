@@ -535,18 +535,10 @@ def mamba2_block(p, x, cfg, *, cache=None, layer=None, return_state=False):
                 new_cache = {"state": st.reshape(B, H, P, S),
                              "conv": window}
         else:
-            state = cache["state"]
-            st = lax.dynamic_index_in_dim(state, layer, 0, False)
-            st = st.reshape(B, G, H // G, P, S) \
-                * jnp.exp(a[:, 0]).reshape(B, G, H // G, 1, 1) \
-                + jnp.einsum("bgs,bghp->bghps", b[:, 0], xin[:, 0])
-            state = lax.dynamic_update_index_in_dim(
-                state, st.reshape(B, H, P, S), layer, 0)
-            # read the row back from the written state, as ssm_block does:
-            # the old state then has no reader after the write
-            st = lax.dynamic_index_in_dim(state, layer, 0, False)
-            y = jnp.einsum("bgs,bghps->bghp", c[:, 0],
-                           st.reshape(B, G, H // G, P, S))[:, None]
+            state, y = ops.ssm_state_step(
+                cache["state"], layer, jnp.exp(a[:, 0]),
+                xin[:, 0].reshape(B, H, P), b[:, 0], c[:, 0])
+            y = y.reshape(B, 1, G, H // G, P)
             new_cache = {"state": state, "conv": conv}
     y = y + xs * p["d_skip"].reshape(G, H // G, 1)
     with jax.named_scope("gate_norm"):

@@ -5,11 +5,13 @@ import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.kernels import ref
 from repro.kernels.aggregate import aggregate
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
+from repro.kernels.ssm_step import mamba2_state_step
 from repro.kernels.xor_code import (xor_decode, xor_decode_gather,
                                     xor_encode, xor_encode_gather, xor_fold)
 
@@ -287,3 +289,66 @@ def test_ssd_scan_chunk_invariance():
             for ch in (8, 16, 64)]
     for o in outs[1:]:
         np.testing.assert_allclose(outs[0], o, rtol=2e-5, atol=2e-5)
+
+
+# --------------------------------------------------------------------- #
+# mamba2 decode state step (fused update + read-out, in place)
+# --------------------------------------------------------------------- #
+def _state_step_inputs(R, B, H, P, S, G, seed):
+    rng = np.random.default_rng(seed)
+    f = lambda *s: jnp.asarray(rng.standard_normal(s).astype(np.float32))
+    state = f(R, B, H, P, S) * 0.5
+    da = jnp.exp(-jnp.abs(f(R, B, H)) * 0.3)          # per layer
+    return state, da, f(R, B, H, P), f(R, B, G, S), f(R, B, G, S)
+
+
+@pytest.mark.parametrize("H,P,S", [(4, 8, 16), (4, 64, 128)])
+@pytest.mark.parametrize("B", [1, 3])
+@pytest.mark.parametrize("G", [1, 2])
+@pytest.mark.parametrize("in_scan", [False, True])
+def test_mamba2_state_step_matches_xla(in_scan, G, B, H, P, S):
+    """The kernel against the XLA decode form on a stack of 3 layers: the
+    updated layer and ``y`` to f32 tolerance, every other layer of the
+    returned stack bitwise unchanged. ``in_scan``: called inside a
+    ``lax.scan`` that carries the stack, as the model's layer scan does,
+    over layers 2 then 0, so layer 1 must come back untouched."""
+    R = 3
+    state, da, xin, b, c = _state_step_inputs(R, B, H, P, S, G, B + G + P)
+    tol = dict(rtol=1e-5, atol=1e-5)
+
+    def run(step, layers):
+        def body(st, l):
+            st, y = step(st, l, da[l], xin[l], b[l], c[l])
+            return st, y
+        if in_scan:
+            return jax.jit(lambda st: lax.scan(body, st, layers))(state)
+        ys = []
+        st = state
+        for l in layers:
+            st, y = body(st, l)
+            ys.append(y)
+        return st, jnp.stack(ys)
+
+    if in_scan:
+        cases = [jnp.asarray([2, 0])]
+    else:
+        cases = [jnp.asarray([l]) for l in range(R)]
+    for layers in cases:
+        got_st, got_y = run(mamba2_state_step, layers)
+        want_st, want_y = run(ref.ssm_state_step_ref, layers)
+        assert got_y.shape == (len(layers), B, H, P)
+        np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                                   **tol)
+        for l in range(R):
+            if l in np.asarray(layers):
+                np.testing.assert_allclose(np.asarray(got_st[l]),
+                                           np.asarray(want_st[l]), **tol)
+            else:
+                np.testing.assert_array_equal(np.asarray(got_st[l]),
+                                              np.asarray(state[l]))
+
+
+def test_mamba2_state_step_rejects_bad_groups():
+    state, da, xin, b, c = _state_step_inputs(2, 1, 4, 8, 16, 3, 0)
+    with pytest.raises(ValueError):
+        mamba2_state_step(state, 0, da[0], xin[0], b[0], c[0])

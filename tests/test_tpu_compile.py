@@ -26,6 +26,7 @@ from repro.kernels import xor_code as X
 from repro.kernels.aggregate import aggregate
 from repro.kernels.flash_attention import flash_attention
 from repro.kernels.ssd_scan import ssd_scan
+from repro.kernels.ssm_step import mamba2_state_step
 
 PK = 65536                      # u32 words per codec packet
 N, M, P = 27, 4, 972            # make_plan(3, 4, d) stage-1 geometry
@@ -124,6 +125,16 @@ def test_ssd_scan_compiles_for_v5e(one_chip):
     bc = ((1, 4096, 64, 128), jnp.float32)
     _compile_has_kernel(
         one_chip, lambda *t: ssd_scan(*t, interpret=False), x, a, bc, bc)
+
+
+def test_mamba2_state_step_compiles_for_v5e(one_chip):
+    """mamba2-1.3b's decode state step: layer l of the 48-layer stack of
+    16 rows x 64 heads x [64, 128] f32 updated in place, y read out."""
+    f32 = jnp.float32
+    _compile_has_kernel(
+        one_chip, lambda *t: mamba2_state_step(*t, interpret=False),
+        ((48, 16, 64, 64, 128), f32), ((), i32), ((16, 64), f32),
+        ((16, 64, 64), f32), ((16, 1, 128), f32), ((16, 1, 128), f32))
 
 
 def test_flash_attention_compiles_for_v5e(one_chip):
@@ -239,7 +250,13 @@ def test_mamba2_wave_writes_both_states_in_place(one_chip):
     mem = compiled.memory_analysis()
     print("mamba2 wave, 16 x 768: arguments", mem.argument_size_in_bytes,
           "temporaries", mem.temp_size_in_bytes)
+    # the XLA form's wave held 1,713,526,272 bytes of temporaries, nearly
+    # all of them w_in's relayout; the fused step adds none of a row's
+    # size (2 MiB a layer), only XLA's small copy slots, 16 KiB each
+    assert mem.temp_size_in_bytes <= 1_713_526_272 + (1 << 20)
     hlo = compiled.as_text()
+    # the decode state step is the fused kernel, not XLA's two passes
+    assert "tpu_custom_call" in hlo and "mamba2_state_step" in hlo
     assert not _pool_copies(hlo, "f32[48,16,64,64,128]")
     copies = _pool_copies(hlo, "bf16[48,16,3,4352]")
     assert all(entry for entry, _, _, _ in copies), copies
