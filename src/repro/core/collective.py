@@ -22,21 +22,17 @@ batches for each of its ``q**(k-2)`` owned jobs; its input here is the
 Output per device: ``out : [J, d]`` — the fully-aggregated shard ``s`` of
 every job (reduce-scatter semantics, the paper's Reduce phase).
 
-Execution modes
----------------
-``mode="batched"`` (default) runs each of the ``k-1`` broadcast rounds
-of stages 1 and 2 as ONE grouped collective over every group at once —
-``2*(k-1)`` batched collectives total, independent of ``J``:
+Routers
+-------
+Each of the ``k-1`` broadcast rounds of stages 1 and 2 runs as ONE
+grouped collective over every group at once — ``2*(k-1)`` batched
+collectives total, independent of ``J``:
 
 * ``router="all_to_all"`` — one ``lax.all_to_all`` per round (a single
   ppermute cannot carry a round: each device must reach ``q`` peers,
   see DESIGN.md §4).
 * ``router="ppermute"`` — ``q`` value-shift sub-permutations per round
   (``2*(k-1)*q`` ppermutes, every byte on the wire useful).
-
-``mode="looped"`` is the legacy per-group schedule — ``(J + n_s2) *
-(k-1)`` tiny ppermutes — kept as the benchmark baseline
-(benchmarks/bench_schedule.py).
 
 Multi-wave streaming (DESIGN.md §9) is :class:`ShuffleStream`: async,
 double-buffered dispatch of this executor with same-shaped waves
@@ -302,8 +298,8 @@ def _gather_decode(recv_flat, flat, rsel, idx, mask, use_kernels: bool):
 
 
 # --------------------------------------------------------------------- #
-# the coded exchange (stages 1 and 2 share everything; the batched and
-# looped modes differ ONLY in how a round's packets move).
+# the coded exchange (stages 1 and 2 share everything; the flat and
+# two-level executors differ ONLY in how a round's packets move).
 #
 # Two codecs execute the same tables (DESIGN.md §10):
 #
@@ -317,7 +313,7 @@ def _gather_decode(recv_flat, flat, rsel, idx, mask, use_kernels: bool):
 #   bit-for-bit (tests/test_codec_fused.py).
 # --------------------------------------------------------------------- #
 def _encode_stage(wire, T: StageTables, me, *, k, pk, codec, use_kernels):
-    """Prologue shared by both modes: the sender-side
+    """Prologue shared by both executors: the sender-side
     Δ = XOR_p pkt(G[p], pos(me, G[p])) (self-row zero).
 
     ``wire`` is the chunk buffer :func:`_wire_buffer` built — u32 wire
@@ -351,7 +347,7 @@ def _encode_stage(wire, T: StageTables, me, *, k, pk, codec, use_kernels):
 
 def _decode_stage(recv, ctx, T: StageTables, me, *, k, pk, codec,
                   use_kernels):
-    """Epilogue shared by both modes: pkt(me, pos(m_r, me)) =
+    """Epilogue shared by both executors: pkt(me, pos(m_r, me)) =
     recv[r] XOR XOR_{p: G[p] not in {m_r, me}} pkt(G[p], pos(m_r, G[p])),
     decoded words landing in their chunk-slot positions."""
     def dev(tab):
@@ -530,33 +526,12 @@ def _stage_coded_two_level(axis_name, wire, T: StageTables,
                          use_kernels=use_kernels)
 
 
-def _stage_coded_looped(axis_name, wire, T: StageTables, rounds_list, me, *,
-                        k, pk, codec, use_kernels):
-    """Legacy exchange — one ppermute per group per round (benchmark
-    baseline; same tables, same encode/decode)."""
-    ctx, delta = _encode_stage(wire, T, me, k=k, pk=pk, codec=codec,
-                               use_kernels=use_kernels)
-    n = T.n
-    valid = jnp.take(jnp.asarray(T.valid), me, axis=0)
-    recv = jnp.zeros((n, k - 1, pk), dtype=jnp.uint32)
-    for gi in range(n):
-        payload = jnp.where(valid[gi], delta[gi], 0)
-        for r in range(1, k):
-            got = lax.ppermute(payload, axis_name,
-                               perm=list(rounds_list[gi][r - 1]))
-            recv = recv.at[gi, r - 1].set(jnp.where(valid[gi], got,
-                                                    recv[gi, r - 1]))
-    return _decode_stage(recv, ctx, T, me, k=k, pk=pk, codec=codec,
-                         use_kernels=use_kernels)
-
-
 # --------------------------------------------------------------------- #
 # the SPMD shuffle body (runs inside shard_map over `axis_name`)
 # --------------------------------------------------------------------- #
 def camr_shuffle(plan: CAMRPlan, contribs: jnp.ndarray, *,
-                 axis_name: str, mode: str = "batched",
-                 router: str = "all_to_all", codec: str = "fused",
-                 use_kernels=None, debug: bool = False,
+                 axis_name: str, router: str = "all_to_all",
+                 codec: str = "fused", use_kernels=None,
                  verify_wire: bool = False, corrupt=None):
     """3-stage CAMR coded shuffle: contribs [J_own, k-1, K, d] -> [J, d].
 
@@ -588,7 +563,7 @@ def camr_shuffle(plan: CAMRPlan, contribs: jnp.ndarray, *,
     delta cannot cancel between a payload fold and its checksum).
     ``corrupt=(stage, device, row, word, bits)`` XORs ``bits`` into
     one outgoing Δ word post-encode — the deterministic fault the
-    chaos layer replays. Requires the fused batched codec; the index
+    chaos layer replays. Requires the fused codec; the index
     tables are row-oriented, so the widened rows reuse the same tables,
     and 16-bit payloads pack to u32 words before widening (the u32
     kernels carry them; the u16 kernels assume unaugmented packets).
@@ -601,23 +576,14 @@ def camr_shuffle(plan: CAMRPlan, contribs: jnp.ndarray, *,
     if contribs.shape != (J_own, k - 1, K, d):
         raise ValueError(f"contribs shape {contribs.shape} != "
                          f"{(J_own, k - 1, K, d)}")
-    if mode not in ("batched", "looped"):
-        raise ValueError(f"unknown mode {mode!r}")
     if codec not in ("fused", "multipass"):
         raise ValueError(f"unknown codec {codec!r}")
     two_level = prog.topology is not None
-    if two_level and mode != "batched":
-        raise ValueError("two-level topology requires mode='batched' "
-                         "(the looped legacy router has no host-aware "
-                         "relay lane)")
     if verify_wire:
-        if codec != "fused" or mode != "batched":
-            raise ValueError("verify_wire requires codec='fused' and "
-                             "mode='batched' (the checksum word rides "
-                             "the row-oriented fused index tables)")
-        if debug:
-            raise ValueError("verify_wire and debug are mutually "
-                             "exclusive (different return shapes)")
+        if codec != "fused":
+            raise ValueError("verify_wire requires codec='fused' (the "
+                             "checksum word rides the row-oriented "
+                             "fused index tables)")
     elif corrupt is not None:
         raise ValueError("corrupt injection without verify_wire "
                          "would silently mis-reduce — exactly the "
@@ -663,20 +629,16 @@ def camr_shuffle(plan: CAMRPlan, contribs: jnp.ndarray, *,
         T = prog.stage_tables(stage)
         spec = ((cdev, crow, cword, cbits)
                 if corrupt is not None and cst == stage else None)
-        if mode == "batched" and two_level:
+        if two_level:
             decoded = _stage_coded_two_level(
                 axis_name, wire, T, prog.host_tables(stage), me, q=q,
                 k=k, K=K, pk=pkv, router=router, codec=codec,
                 use_kernels=use_kernels, corrupt=spec)
-        elif mode == "batched":
+        else:
             decoded = _stage_coded_batched(
                 axis_name, wire, T, me, q=q, k=k, K=K, pk=pkv,
                 router=router, codec=codec, use_kernels=use_kernels,
                 corrupt=spec)
-        else:
-            decoded = _stage_coded_looped(
-                axis_name, wire, T, prog.round_perms(stage), me,
-                k=k, pk=pk, codec=codec, use_kernels=use_kernels)
         if verify_wire:
             # recompute each decoded row's checksum; non-member rows
             # decode garbage by design and are masked out (T.valid)
@@ -725,29 +687,22 @@ def camr_shuffle(plan: CAMRPlan, contribs: jnp.ndarray, *,
     s3_sel = s3_out[d_s3, d_slot]
     nonowner_val = s2_sel + s3_sel
     out = jnp.where(d_isown[:, None], owner_val, nonowner_val)
-    if debug:
-        return dict(out=out, stage1=stage1_val, stage2=s2_sel, stage3=s3_sel,
-                    own_sum=own_sum[d_slot], is_own=d_isown)
     if verify_wire:
         return out, bad
     return out
 
 
-def expected_collective_calls(plan: CAMRPlan, mode: str = "batched",
+def expected_collective_calls(plan: CAMRPlan,
                               router: str = "all_to_all") -> dict[str, int]:
-    """Collectives per shuffle — what each mode traces (tested against
+    """Collectives per shuffle — what each router traces (tested against
     the jaxpr in tests/test_collective.py). On a two-level topology the
     phase-B relay adds one intra-host ppermute per live (round, shift)
     lane of each coded stage."""
     q, k = plan.q, plan.k
-    if mode == "batched":
-        s12 = 2 * (k - 1) if router == "all_to_all" else 2 * (k - 1) * q
-        if plan.topology is not None:
-            s12 += sum(len(live) for X in (plan.program.hx1,
-                                           plan.program.hx2)
-                       for live in X.b_live)
-    else:
-        s12 = (plan.J + plan.program.n_s2) * (k - 1)
+    s12 = 2 * (k - 1) if router == "all_to_all" else 2 * (k - 1) * q
+    if plan.topology is not None:
+        s12 += sum(len(live) for X in (plan.program.hx1, plan.program.hx2)
+                   for live in X.b_live)
     return dict(stage12=s12, stage3=q - 1, total=s12 + q - 1)
 
 
@@ -828,9 +783,8 @@ class ShuffleStream:
 
     def __init__(self, q: int, k: int, d: int, *, mesh,
                  axis_name: str = "camr", depth: int = 2,
-                 wave_batch: int = 1, mode: str = "batched",
-                 router: str = "all_to_all", codec: str = "fused",
-                 use_kernels=None, degraded_lane: str = "device",
+                 wave_batch: int = 1, router: str = "all_to_all",
+                 codec: str = "fused", use_kernels=None,
                  topology: Topology | None = None,
                  gateway_avoid=frozenset(), verify_wire: bool = False,
                  max_replays: int = 2):
@@ -852,22 +806,15 @@ class ShuffleStream:
         self.axis_name = axis_name
         self.depth = depth
         self.wave_batch = wave_batch
-        self.mode = mode
         self.router = router
         if codec not in ("fused", "multipass"):
             raise ValueError(f"unknown codec {codec!r}")
         self.codec = codec
         self.use_kernels = use_kernels
-        if degraded_lane not in ("device", "host"):
-            raise ValueError(f"unknown degraded_lane {degraded_lane!r}")
-        self.degraded_lane = degraded_lane
         from .schedule import resolve_topology
         self.topology = resolve_topology(topology, q, k)
         if self.topology is not None:
             self.topology.check(q, k)
-            if mode != "batched":
-                raise ValueError("two-level topology requires "
-                                 "mode='batched'")
         self._gateway_avoid = frozenset(int(x)
                                         for x in (gateway_avoid or ()))
         if any(not 0 <= x < self.K for x in self._gateway_avoid):
@@ -875,9 +822,8 @@ class ShuffleStream:
                              f"{sorted(self._gateway_avoid)} has "
                              f"devices outside [0, {self.K})")
         self.verify_wire = bool(verify_wire)
-        if self.verify_wire and (codec != "fused" or mode != "batched"):
-            raise ValueError("verify_wire requires codec='fused' and "
-                             "mode='batched'")
+        if self.verify_wire and codec != "fused":
+            raise ValueError("verify_wire requires codec='fused'")
         if max_replays < 0:
             raise ValueError("max_replays must be >= 0")
         self.max_replays = max_replays
@@ -920,8 +866,7 @@ class ShuffleStream:
 
             def body(c):
                 r = camr_shuffle(plan, c[0], axis_name=self.axis_name,
-                                 mode=self.mode, router=self.router,
-                                 codec=self.codec,
+                                 router=self.router, codec=self.codec,
                                  use_kernels=self.use_kernels,
                                  verify_wire=verify, corrupt=corrupt)
                 if verify:
@@ -954,9 +899,6 @@ class ShuffleStream:
         t = resolve_topology(topology, self.q, self.k)
         if t is not None:
             t.check(self.q, self.k)
-            if self.mode != "batched":
-                raise ValueError("two-level topology requires "
-                                 "mode='batched'")
         if t != self.topology:
             self.topology = t
             self.host_swaps += 1
@@ -1071,8 +1013,9 @@ class ShuffleStream:
         (:func:`repro.runtime.fault.build_degraded_executor`, served
         from the process-wide EXEC_CACHE — zero retraces after
         :meth:`warm_degraded_execs`), bitwise-identical to the fault
-        runtime's host interpreter, which remains available as the
-        ``degraded_lane="host"`` fallback/oracle. The compiled healthy
+        runtime's host interpreter
+        (:func:`repro.runtime.fault.degraded_shuffle_host`, the
+        reference the tests hold it to). The compiled healthy
         executors stay resident either way, so :meth:`restore` is
         retrace-free (``compiles`` flat).
         """
@@ -1149,24 +1092,14 @@ class ShuffleStream:
 
     def _degraded_exec(self, buf, W: int):
         """Degraded wave over the stacked [K, J_own, k-1, K, W*d]
-        tensor, bitwise-identical to the healthy executor's output
-        (DESIGN.md §11), in logical slots. ``degraded_lane="device"``
-        dispatches the compiled dense executor (async, output stays on
-        device); ``"host"`` interprets the re-lowering in numpy — the
-        fallback and the oracle the device lane is gated against."""
-        if self.degraded_lane == "device":
-            dtype = getattr(buf, "dtype", None)
-            if dtype is None:
-                buf = np.asarray(buf)
-                dtype = buf.dtype
-            return self._degraded_fn(W, dtype)(jnp.asarray(buf))
-        from repro.runtime.fault import degraded_shuffle_host
-        prog = SCHEDULE_CACHE.program(self.q, self.k, Q=self.K,
-                                      d=W * self.d,
-                                      topology=self.topology,
-                                      gateway_avoid=self._gw())
-        return degraded_shuffle_host(prog, self._failed,
-                                     np.asarray(buf))
+        tensor through the compiled dense executor (async, output stays
+        on device), bitwise-identical to the healthy executor's output
+        (DESIGN.md §11), in logical slots."""
+        dtype = getattr(buf, "dtype", None)
+        if dtype is None:
+            buf = np.asarray(buf)
+            dtype = buf.dtype
+        return self._degraded_fn(W, dtype)(jnp.asarray(buf))
 
     def _check_wave(self, contribs) -> None:
         shape = (self.K, self.q ** (self.k - 2), self.k - 1, self.K,
@@ -1220,7 +1153,6 @@ class ShuffleStream:
                     swaps=self.swaps,
                     failed=tuple(sorted(self._failed)),
                     degraded_compiles=self.degraded_compiles,
-                    degraded_lane=self.degraded_lane,
                     topology=(None if self.topology is None
                               else self.topology.key()),
                     gateway_avoid=tuple(sorted(self._gw())),
@@ -1320,8 +1252,9 @@ def camr_edge_bytes(plan: CAMRPlan, itemsize: int = 4,
     two-level topology; phase-B relay hops (``b_send``) are intra-host
     by construction. Stage-3 unicasts are intra-class and parallel
     classes sit inside host blocks (``hosts | k``), so stage 3 never
-    crosses under either schedule. ``benchmarks/bench_topology.py``
-    gates these measured counts against the analytic
+    crosses under either schedule.
+    ``tests/test_topology.py::test_edge_bytes_match_hierarchical_loads``
+    holds these measured counts to the analytic
     :func:`repro.core.loads.camr_load_hierarchical` prediction.
 
     Requires a plan lowered with a two-level topology (the flat plan

@@ -602,11 +602,6 @@ class ShuffleProgram:
             raise ValueError("program lowered without device tables")
         return self.d // (self.k - 1)
 
-    @property
-    def n_batched_collectives(self) -> int:
-        """Batched collectives issued for stages 1+2 (all_to_all router)."""
-        return 2 * (self.k - 1)
-
     def stage_tables(self, stage: int) -> StageTables:
         t = self.s1 if stage == 1 else self.s2
         if t is None:
@@ -625,18 +620,6 @@ class ShuffleProgram:
 
     def group_members(self, row: int) -> tuple[int, ...]:
         return tuple(int(x) for x in self.groups[row])
-
-    def round_perms(self, stage: int) -> tuple:
-        """Per-group per-round (src, dst) pairs for the LOOPED legacy
-        router: round ``r`` sends ``G[p] -> G[(p+r) % k]``."""
-        k = self.k
-        out = []
-        for row in self.stage_rows(stage):
-            G = self.group_members(int(row))
-            out.append(tuple(
-                tuple((G[p], G[(p + r) % k]) for p in range(k))
-                for r in range(1, k)))
-        return tuple(out)
 
     def coded_chunks(self, row: int) -> list[tuple[int, int, int]]:
         """[(receiver, job, batch)] for one group row — engine view."""

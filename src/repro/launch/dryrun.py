@@ -4,11 +4,11 @@ For every (architecture x input shape) cell, ``jax.jit(step).lower(...)
 .compile()`` against the production mesh, then record:
 
 * ``memory_analysis()``  — proves the cell fits per-device HBM,
-* ``cost_analysis()``    — HLO FLOPs / bytes for §Roofline,
+* ``cost_analysis()``    — HLO FLOPs / bytes,
 * collective bytes       — parsed from the optimized HLO (hlo_stats).
 
-Results go to ``results/dryrun/<arch>_<shape>_<mesh>.json``; the roofline
-tooling (launch/roofline.py) and EXPERIMENTS.md read from there.
+Results go to ``results/dryrun/<arch>_<shape>_<mesh>.json``. The run
+is a compile-and-fit check: it times nothing on a device.
 
 Usage:
     PYTHONPATH=src python -m repro.launch.dryrun --arch granite_3_2b \

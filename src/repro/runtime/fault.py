@@ -712,10 +712,10 @@ def degraded_shuffle_host(program, failed, contribs) -> np.ndarray:
     the canonical combine order the output is BITWISE equal to the
     healthy shuffle of the same contributions (DESIGN.md §11).
 
-    This is the :class:`~repro.core.collective.ShuffleStream` degraded
-    lane — collective.py imports it lazily (runtime layering: the SPMD
-    stream borrows the fault runtime's interpreter rather than lowering
-    a second degraded executor).
+    This is the reference the compiled degraded executor
+    (:func:`build_degraded_executor`, which
+    :class:`~repro.core.collective.ShuffleStream` dispatches) is held
+    to bitwise in the tests.
     """
     deg = SCHEDULE_CACHE.degraded(program, set(failed))
     design, pl = program.design, program.placement

@@ -388,8 +388,8 @@ class MultiModelCAMRTrainer:
                     + " Or pass mesh=.")
             self._stream = ShuffleStream(
                 self.q, self.k, self.d_shard, mesh=self.mesh,
-                axis_name=self.axis_name, mode="batched",
-                router=self.router, codec=self.codec,
+                axis_name=self.axis_name, router=self.router,
+                codec=self.codec,
                 use_kernels=self.use_kernels)
         # reconcile with the trainer's failed set (covers both a lazy
         # first build under failure and a direct self.failed mutation)

@@ -182,7 +182,7 @@ def _identity_map(job, sf):
 
 def make_specs(q: int, k: int, waves: int, d: int = 8,
                seed: int = 0) -> list:
-    """Waves of pre-mapped values (map = identity), like the benches."""
+    """Waves of pre-mapped values (map = identity)."""
     cfg = CAMRConfig(q=q, k=k, gamma=1)
     Q = cfg.num_functions()
     rng = np.random.default_rng(seed)

@@ -9,6 +9,7 @@ import subprocess
 import sys
 import textwrap
 
+import numpy as np
 import pytest
 
 from chaos import CorruptPacket, FaultPlan, Kill, KillHost, RejoinHost
@@ -138,6 +139,44 @@ _RUN_CORRUPT = _PRELUDE + textwrap.dedent("""
     assert st['wire_replays'] == 2, st
     print('OK')
 """)
+
+
+@pytest.mark.parametrize("itemsize", [4, 2], ids=["f32", "bf16"])
+@pytest.mark.parametrize("q,k", [(2, 4), (2, 6), (3, 4)])
+def test_checksum_word_catches_every_single_word_flip(q, k, itemsize):
+    """The integrity lane's checksum word, the XOR of a packet's ``pk``
+    payload words folded by the codec's own reduction: it widens each
+    packet by exactly one word, passes every clean packet, flags every
+    single-word flip of payload or checksum, and commutes with the XOR
+    codec (checksums of XOR-combined packets XOR-combine)."""
+    import jax.numpy as jnp
+
+    from repro.core.collective import _xor_reduce
+    from repro.core.schedule import payload_words
+
+    wp = payload_words(2 * (k - 1), itemsize, k)
+    assert wp % (k - 1) == 0
+    pk = wp // (k - 1)
+    rng = np.random.default_rng(q * 10 + k)
+    w = rng.integers(0, 2 ** 32, size=(8, k - 1, pk), dtype=np.uint32)
+    csum = np.asarray(_xor_reduce(jnp.asarray(w), 2))
+    aug = np.concatenate([w, csum[..., None]], axis=2)
+    assert aug.size * pk == w.size * (pk + 1)
+
+    def flagged(x):                     # [..., k-1, pk+1] -> bad rows
+        calc = _xor_reduce(jnp.asarray(x[..., :pk]), x.ndim - 1)
+        return np.asarray(calc) != x[..., pk]
+
+    assert not flagged(aug).any()
+    flips = []
+    for r in range(k - 1):
+        for word in range(pk + 1):
+            for bits in (1, 0x80000000, 0xDEADBEEF):
+                t = aug[0].copy()
+                t[r, word] ^= np.uint32(bits)
+                flips.append(t)
+    assert flagged(np.stack(flips)).any(axis=1).all()
+    assert not flagged(aug[0] ^ aug[1]).any()
 
 
 @pytest.mark.parametrize("dtype", ["np.float32", "jnp.bfloat16"])

@@ -55,13 +55,10 @@ def _stage_codec_outputs(program, stage, u32, me, k, pk, seed):
     return outs
 
 
-@given(st.integers(2, 3), st.integers(3, 4), st.sampled_from([1, 2, 5]),
-       st.integers(0, 10**6), st.booleans())
-@settings(max_examples=12, deadline=None)
-def test_fused_codec_bit_identical(q, k, pk, seed, degraded):
+def check_fused_codec_bit_identical(q, k, pk, seed, degraded) -> None:
     """delta and decoded chunks agree bit-for-bit across all lanes, for
-    every device, both stages — programs served directly or via the
-    survivor-set re-lowering."""
+    every probed device, both stages — programs served directly or via
+    the survivor-set re-lowering."""
     d = pk * (k - 1)
     K, J_own = q * k, q ** (k - 2)
     program = _CACHE.program(q, k, Q=K, d=d)
@@ -93,6 +90,21 @@ def test_fused_codec_bit_identical(q, k, pk, seed, degraded):
                 np.testing.assert_array_equal(
                     chunk, ref[1],
                     err_msg=f"chunk {codec}/uk={uk} s={me} stage={stage}")
+
+
+@given(st.integers(2, 3), st.integers(3, 4), st.sampled_from([1, 2, 5]),
+       st.integers(0, 10**6), st.booleans())
+@settings(max_examples=12, deadline=None)
+def test_fused_codec_bit_identical(q, k, pk, seed, degraded):
+    check_fused_codec_bit_identical(q, k, pk, seed, degraded)
+
+
+@pytest.mark.parametrize("q,k,pk", [(2, 3, 32), (2, 4, 16), (3, 3, 8),
+                                    (2, 3, 8)])
+def test_fused_codec_bit_identical_cases(q, k, pk):
+    """Packets of up to 32 words, wider than the property test draws."""
+    check_fused_codec_bit_identical(q, k, pk, seed=q * 100 + k * 10 + pk,
+                                    degraded=False)
 
 
 @given(st.integers(2, 3), st.integers(3, 4), st.integers(0, 10**6))

@@ -32,7 +32,8 @@ import jax.numpy as jnp
 import ml_dtypes
 
 from repro.core.collective import (_decode_stage, _encode_stage,
-                                   _from_wire, _wire_buffer)
+                                   _from_wire, _wire_buffer,
+                                   camr_collective_bytes, make_plan)
 from repro.core.schedule import (ScheduleCache, pack_payload,
                                  payload_words, unpack_payload)
 
@@ -178,10 +179,26 @@ def check_packed_codec_bit_identical(q, k, d, seed, degraded,
     (2, 3, 6, True, "bfloat16"),      # word pad (w=3 -> wp=4)
     (2, 4, 9, False, "float16"),      # odd trailing lane
     (3, 3, 4, True, "float16"),
+    (2, 3, 64, False, "bfloat16"),    # wider packets: 4-16 words
+    (2, 4, 48, False, "bfloat16"),
+    (3, 3, 16, False, "bfloat16"),
+    (2, 3, 16, False, "bfloat16"),
 ])
 def test_packed_codec_bit_identical_cases(q, k, d, degraded, dtype):
     check_packed_codec_bit_identical(q, k, d, seed=q * 100 + d,
                                      degraded=degraded, dtype=dtype)
+
+
+@pytest.mark.parametrize("q,k,d", [(2, 3, 64), (2, 4, 48), (3, 3, 16),
+                                   (2, 3, 16)])
+def test_packed_lane_bytes_on_wire(q, k, d):
+    """The packed lane ships at most 0.55x the f32 lane's bytes on the
+    wire for the same element payload: half, plus at most ``k-2`` pad
+    words per shard."""
+    plan = make_plan(q, k, d)
+    f32 = camr_collective_bytes(plan, dtype=jnp.float32)["camr_total"]
+    b16 = camr_collective_bytes(plan, dtype=jnp.bfloat16)["camr_total"]
+    assert b16 <= 0.55 * f32, (b16, f32)
 
 
 def check_packed_wire_mirrors_numpy(q, d, seed) -> None:

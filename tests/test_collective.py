@@ -46,23 +46,21 @@ _RUN = textwrap.dedent("""
         return n
 
     first = None
-    for mode, router, codec in [('batched', 'all_to_all', 'fused'),
-                                ('batched', 'ppermute', 'fused'),
-                                ('looped', 'all_to_all', 'fused'),
-                                ('batched', 'all_to_all', 'multipass')]:
+    for router, codec in [('all_to_all', 'fused'), ('ppermute', 'fused'),
+                          ('all_to_all', 'multipass')]:
         fn = shard_map(
-            lambda c: camr_shuffle(plan, c[0], axis_name='camr', mode=mode,
+            lambda c: camr_shuffle(plan, c[0], axis_name='camr',
                                    router=router, codec=codec)[None],
             mesh=mesh, in_specs=P('camr'), out_specs=P('camr'))
         out = np.asarray(jax.jit(fn)(contribs))
         np.testing.assert_allclose(out, ref, rtol=2e-5, atol=2e-6)
         # fused and multipass codecs are BIT-identical, not just close
         first = out if first is None else first
-        np.testing.assert_array_equal(out, first, err_msg=(mode, codec))
+        np.testing.assert_array_equal(out, first, err_msg=(router, codec))
         counts = count_collectives(jax.make_jaxpr(fn)(contribs))
-        want = expected_collective_calls(plan, mode, router)
+        want = expected_collective_calls(plan, router)
         got12 = counts['all_to_all'] + counts['ppermute'] - (q - 1)
-        assert got12 == want['stage12'], (mode, router, counts, want)
+        assert got12 == want['stage12'], (router, counts, want)
         assert counts['ppermute'] + counts['all_to_all'] == want['total']
 
     g = jax.jit(shard_map(
@@ -177,7 +175,7 @@ def _run_subprocess(code: str, ndev: int) -> str:
 
 
 @pytest.mark.parametrize("q,k,d", [(2, 3, 8), (4, 3, 16), (3, 4, 9),
-                                   (2, 4, 6)])
+                                   (2, 4, 6), (5, 3, 8)])
 def test_camr_shuffle_multidevice(q, k, d):
     out = _run_subprocess(_RUN.format(q=q, k=k, d=d), ndev=q * k)
     assert "OK" in out
@@ -203,14 +201,11 @@ def test_shuffle_stream_multidevice(q, k, d):
 
 def test_expected_collective_calls_model():
     plan = make_plan(4, 3, 16)
-    want = expected_collective_calls(plan, "batched", "all_to_all")
+    want = expected_collective_calls(plan, "all_to_all")
     # the headline number: 2*(k-1) batched collectives for stages 1+2,
-    # independent of J (the looped path needs (J + n_s2)*(k-1) = 128)
+    # independent of J
     assert want["stage12"] == 2 * (plan.k - 1) == 4
-    looped = expected_collective_calls(plan, "looped")
-    assert looped["stage12"] == (plan.J + plan.program.n_s2) * (plan.k - 1)
-    assert looped["stage12"] == 128
-    pp = expected_collective_calls(plan, "batched", "ppermute")
+    pp = expected_collective_calls(plan, "ppermute")
     assert pp["stage12"] == 2 * (plan.k - 1) * plan.q
 
 

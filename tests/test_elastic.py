@@ -80,6 +80,25 @@ def test_chaos_sweep(q, k, pipeline):
         _run_sweep(q, k, plan, pipeline)
 
 
+def test_churn_on_a_cold_cache_then_warm():
+    """Kill and rejoin mid-stream on a CLEARED schedule cache: the kill
+    boundary lowers on the critical path and the output still matches
+    the serial oracle bitwise. After ``warm_survivors`` the same churn
+    lowers nothing."""
+    specs = chaos.make_specs(2, 4, waves=8, d=32)
+    oracle = serial_oracle(specs)
+    plan = FaultPlan((Kill(3, 2), Rejoin(6, 2)), "kill-rejoin")
+    SCHEDULE_CACHE.clear()
+    got, stream, _ = run_plan(specs, plan)
+    assert_bit_identical(oracle, got, "cold")
+    assert stream.last_report.cache_misses > 0
+    SCHEDULE_CACHE.warm_survivors(
+        CAMREngine(specs[0].cfg, specs[0].map_fn).program)
+    got, stream, _ = run_plan(specs, plan)
+    assert_bit_identical(oracle, got, "warm")
+    assert stream.last_report.cache_misses == 0
+
+
 # --------------------------------------------------------------------- #
 # in-flight migration: membership changes AFTER a batch mapped
 # --------------------------------------------------------------------- #
@@ -397,24 +416,23 @@ _RUN_DEGRADED_DEVICE = textwrap.dedent("""
     from repro.compat import make_mesh
     from repro.core.collective import (ShuffleStream, make_plan,
                                        scatter_contributions)
+    from repro.runtime.fault import degraded_shuffle_host
 
     q, k, d = 2, 3, 8
     plan = make_plan(q, k, d)
+    prog = plan.program
     mesh = make_mesh((plan.K,), ("camr",))
     rng = np.random.default_rng(3)
     contribs = [scatter_contributions(
         plan, rng.standard_normal((plan.J, k, plan.K, d)).astype(
             np.float32)) for _ in range(4)]
 
-    # oracle lane: the fault runtime's host interpreter
-    host = ShuffleStream(q, k, d, mesh=mesh, degraded_lane="host")
-    host.degrade({4})
-    want = [np.asarray(o) for o in host.run_waves(contribs)]
-    assert host.stats()["degraded_compiles"] == 0, host.stats()
+    # oracle: the fault runtime's host interpreter, wave by wave
+    want = [degraded_shuffle_host(prog, {4}, c) for c in contribs]
 
     # device lane, warmed BEFORE any failure: the degrade itself and
     # every degraded dispatch must then be completely build-free
-    dev = ShuffleStream(q, k, d, mesh=mesh)   # degraded_lane="device"
+    dev = ShuffleStream(q, k, d, mesh=mesh)
     n = dev.warm_degraded_execs(max_failures=1)
     assert n == plan.K, n                     # every single-failure set
     warmed = dev.stats()["degraded_compiles"]
@@ -433,10 +451,9 @@ _RUN_DEGRADED_DEVICE = textwrap.dedent("""
     dev2.degrade({1})
     got2 = [np.asarray(o) for o in dev2.run_waves(contribs)]
     assert dev2.stats()["degraded_compiles"] == 0, dev2.stats()
-    host2 = ShuffleStream(q, k, d, mesh=mesh, degraded_lane="host")
-    host2.degrade({1})
-    for w, g in zip(host2.run_waves(contribs), got2):
-        np.testing.assert_array_equal(np.asarray(w), g)
+    for c, g in zip(contribs, got2):
+        np.testing.assert_array_equal(
+            degraded_shuffle_host(prog, {1}, c), g)
     print("OK")
 """)
 
