@@ -34,10 +34,11 @@ class ModelConfig:
     #   'local'       sliding-window attention + MLP (gemma2 alternation)
     #   'ssm'         simplified SSD block (no conv, no gated norm)
     #   'mamba2'      the published Mamba2 block: fused input projection,
-    #                 depthwise causal conv, SSD, gated RMSNorm
+    #                 depthwise causal conv, SSD, gated RMSNorm; followed
+    #                 by norm2 + MLP when d_ff > 0 (granitemoehybrid)
     #   'shared_attn' attention block with weights SHARED across repeats
     pattern: tuple = ("attn",)
-    rope_theta: float = 1e4
+    rope_theta: float | None = 1e4      # None: no rotary (NoPE)
     window: int | None = None           # SWA width for 'attn' layers
     local_window: int | None = None     # width for 'local' layers
     attn_softcap: float | None = None
@@ -45,6 +46,11 @@ class ModelConfig:
     mlp_act: str = "swiglu"             # swiglu | geglu
     tie_embeddings: bool = False
     scale_embed: bool = False           # gemma2 sqrt(d) embedding scale
+    # granite-style scalars; None leaves the path as it is
+    attn_scale: float | None = None     # softmax scale (None: hd ** -0.5)
+    embedding_multiplier: float | None = None   # on the embedded tokens
+    residual_multiplier: float | None = None    # on each sublayer output
+    logits_scaling: float | None = None         # logits divided by it
     # MoE
     n_experts: int = 0
     experts_per_token: int = 0
@@ -126,7 +132,7 @@ class ModelConfig:
                   + 3 * H + di)                     # dt_bias, A, D, norm
         per = {"attn": attn + mlp, "local": attn + mlp,
                "shared_attn": attn + mlp, "ssm": ssm + d,
-               "mamba2": mamba2 + d}
+               "mamba2": mamba2 + d + (mlp + d if f else 0)}
         reps = self.repeats
         total = 0
         for kind in self.pattern:
@@ -155,7 +161,7 @@ SHAPES = {
 ARCHS = [
     "internvl2_26b", "mixtral_8x7b", "moonshot_v1_16b_a3b", "internlm2_20b",
     "gemma2_2b", "mistral_large_123b", "granite_3_2b", "zamba2_2p7b",
-    "mamba2_1p3b", "seamless_m4t_large_v2",
+    "mamba2_1p3b", "seamless_m4t_large_v2", "granite_4_h_micro",
 ]
 
 
@@ -184,7 +190,9 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
     """Small same-family variant: few layers, tiny widths/tables."""
     kw = dict(
         n_layers=2 * len(cfg.pattern), d_model=64, n_heads=4, n_kv_heads=2,
-        head_dim=16, d_ff=128, vocab=256, dtype="float32", loss_chunk=64,
+        # no MLP stays none: a 'mamba2' sublayer gains one from d_ff > 0
+        head_dim=16, d_ff=128 if cfg.d_ff else 0, vocab=256,
+        dtype="float32", loss_chunk=64,
         microbatches=1,
     )
     if cfg.n_experts:

@@ -104,7 +104,7 @@ def attention_block(p, x, positions, cfg, *, window=None, softcap=None,
     Ts = src.shape[1]
     k = dense(src, p["wk"]).reshape(B, Ts, hkv, dh).transpose(0, 2, 1, 3)
     v = dense(src, p["wv"]).reshape(B, Ts, hkv, dh).transpose(0, 2, 1, 3)
-    if memory is None:
+    if memory is None and cfg.rope_theta is not None:   # None: NoPE
         q = rope(q, positions, cfg.rope_theta)
         k = rope(k, positions, cfg.rope_theta)
 
@@ -176,7 +176,8 @@ def attention_block(p, x, positions, cfg, *, window=None, softcap=None,
     k = constrain(k, ("batch", "heads", None, None))
     v = constrain(v, ("batch", "heads", None, None))
     out = ops.attention(q, k, v, causal=causal and memory is None,
-                        window=window, softcap=softcap, valid_len=valid_len,
+                        window=window, softcap=softcap, scale=cfg.attn_scale,
+                        valid_len=valid_len,
                         use_pallas=cfg.use_pallas,
                         block_q=cfg.attn_block, block_k=cfg.attn_block,
                         unroll=cfg.scan_unroll)

@@ -1,6 +1,6 @@
 """Model assembly: decoder-only / MoE / SSM / hybrid / enc-dec LMs.
 
-One code path serves all ten assigned architectures, driven by
+One code path serves all eleven architectures, driven by
 ``ModelConfig.pattern`` (the repeating sublayer unit) with ``lax.scan``
 over the ``repeats`` axis and optional per-unit remat. Entry points:
 
@@ -45,16 +45,26 @@ def slot_names(cfg: ModelConfig) -> list[str]:
     return [f"{i}_{kind}" for i, kind in enumerate(cfg.pattern)]
 
 
+def _init_ffn(key, cfg) -> Params:
+    """``norm2`` and the MLP (or MoE) that follows a mixer."""
+    ffn = L.init_moe(key, cfg) if cfg.n_experts else L.init_mlp(key, cfg)
+    return {"norm2": jnp.zeros((cfg.d_model,), jnp.float32),
+            "moe" if cfg.n_experts else "mlp": ffn}
+
+
+def _spec_ffn(cfg) -> Any:
+    if cfg.n_experts:
+        return {"norm2": (None,), "moe": L.spec_moe(cfg)}
+    return {"norm2": (None,), "mlp": L.spec_mlp(cfg)}
+
+
 def _init_slot(key, cfg, kind: str) -> Params:
     d = cfg.d_model
     z = jnp.zeros((d,), jnp.float32)
     if kind in ("attn", "local", "shared_attn"):
         k1, k2, k3 = jax.random.split(key, 3)
-        p = {"norm1": z, "attn": L.init_attention(k1, cfg), "norm2": z}
-        if cfg.n_experts:
-            p["moe"] = L.init_moe(k2, cfg)
-        else:
-            p["mlp"] = L.init_mlp(k2, cfg)
+        p = {"norm1": z, "attn": L.init_attention(k1, cfg),
+             **_init_ffn(k2, cfg)}
         if cfg.family == "encdec" and kind == "attn":
             p["norm_x"] = z
             p["cross"] = L.init_attention(k3, cfg)
@@ -62,18 +72,17 @@ def _init_slot(key, cfg, kind: str) -> Params:
     if kind == "ssm":
         return {"norm": z, "ssm": L.init_ssm(key, cfg)}
     if kind == "mamba2":
-        return {"norm": z, "mixer": L.init_mamba2(key, cfg)}
+        p = {"norm": z, "mixer": L.init_mamba2(key, cfg)}
+        if cfg.d_ff:
+            p.update(_init_ffn(jax.random.fold_in(key, 1), cfg))
+        return p
     raise ValueError(f"unknown sublayer kind {kind!r}")
 
 
 def _spec_slot(cfg, kind: str) -> Any:
     if kind in ("attn", "local", "shared_attn"):
         p = {"norm1": (None,), "attn": L.spec_attention(cfg),
-             "norm2": (None,)}
-        if cfg.n_experts:
-            p["moe"] = L.spec_moe(cfg)
-        else:
-            p["mlp"] = L.spec_mlp(cfg)
+             **_spec_ffn(cfg)}
         if cfg.family == "encdec" and kind == "attn":
             p["norm_x"] = (None,)
             p["cross"] = L.spec_attention(cfg)
@@ -81,7 +90,10 @@ def _spec_slot(cfg, kind: str) -> Any:
     if kind == "ssm":
         return {"norm": (None,), "ssm": L.spec_ssm(cfg)}
     if kind == "mamba2":
-        return {"norm": (None,), "mixer": L.spec_mamba2(cfg)}
+        p = {"norm": (None,), "mixer": L.spec_mamba2(cfg)}
+        if cfg.d_ff:
+            p.update(_spec_ffn(cfg))
+        return p
     raise ValueError(kind)
 
 
@@ -161,6 +173,26 @@ def _norm(cfg, x, scale):
     return L.rms_norm(x, scale, cfg.norm_eps).astype(cfg.jdtype)
 
 
+def _add(cfg, x, h):
+    """The residual add of a sublayer's output ``h``, scaled first by
+    ``residual_multiplier`` where the config has one, and reduce-scattered
+    into the sequence-parallel layout."""
+    if cfg.residual_multiplier is not None:
+        h = h * jnp.asarray(cfg.residual_multiplier, h.dtype)
+    return x + constrain(h, ("batch", "seq", "embed"))
+
+
+def _ffn(cfg, p, x):
+    """``norm2`` and the MLP (or MoE) after a mixer: (x, aux)."""
+    with jax.named_scope("mlp"):
+        h = _norm(cfg, x, p["norm2"])
+        if cfg.n_experts:
+            h, aux = L.moe_block(p["moe"], h, cfg)
+        else:
+            h, aux = L.mlp_block(p["mlp"], h, cfg), jnp.zeros((), jnp.float32)
+        return _add(cfg, x, h), aux
+
+
 def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 cache_index=None, layer=None, mode="train"):
     """Returns (x, new_cache_entry, aux).
@@ -168,7 +200,6 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
     ``layer`` set (decode): ``cache`` is the slot's whole stacked
     ``[R, ...]`` entry, read and written in place at that index."""
     aux = jnp.zeros((), jnp.float32)
-    sp = ("batch", "seq", "embed")  # sequence-parallel residual layout
     if kind in ("attn", "local", "shared_attn"):
         window = cfg.local_window if kind == "local" else cfg.window
         # named scopes: a profile splits the step's device time by them
@@ -180,7 +211,7 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 softcap=cfg.attn_softcap, causal=(mode != "encoder"),
                 cache=attn_cache, cache_index=cache_index, layer=layer)
             # reduce-scatter the row-parallel output into the SP layout
-            x = x + constrain(h, sp)
+            x = _add(cfg, x, h)
             new_cross = None
             if (cfg.family == "encdec" and kind == "attn"
                     and mode != "encoder"):
@@ -202,14 +233,8 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 else:
                     h, _ = L.attention_block(p["cross"], h, positions, cfg,
                                              causal=False, memory=memory)
-                x = x + constrain(h, sp)
-        with jax.named_scope("mlp"):
-            h = _norm(cfg, x, p["norm2"])
-            if cfg.n_experts:
-                h, aux = L.moe_block(p["moe"], h, cfg)
-            else:
-                h = L.mlp_block(p["mlp"], h, cfg)
-            x = x + constrain(h, sp)
+                x = _add(cfg, x, h)
+        x, aux = _ffn(cfg, p, x)
         new_cache = None
         if cache is not None:
             new_cache = {"self": new_self}
@@ -230,14 +255,17 @@ def _apply_slot(cfg, kind, p, x, positions, *, memory=None, cache=None,
                 h, new_state = L.ssm_block(p["ssm"], h, cfg,
                                            state=cache["state"], layer=layer)
                 new_cache = {"state": new_state}
-            return x + constrain(h, sp), new_cache, aux
+            return _add(cfg, x, h), new_cache, aux
     if kind == "mamba2":
         # scopes inside: conv, ssd, gate_norm (layers.mamba2_block)
         with jax.named_scope("ssm"):
             h, new_cache = L.mamba2_block(
                 p["mixer"], _norm(cfg, x, p["norm"]), cfg, cache=cache,
                 layer=layer, return_state=(mode == "prefill"))
-            return x + constrain(h, sp), new_cache, aux
+            x = _add(cfg, x, h)
+        if cfg.d_ff:
+            x, aux = _ffn(cfg, p, x)
+        return x, new_cache, aux
     raise ValueError(kind)
 
 
@@ -289,11 +317,18 @@ def _scan_units(cfg, params, x, positions, *, cache=None, cache_index=None,
 # --------------------------------------------------------------------- #
 # embedding / logits / loss
 # --------------------------------------------------------------------- #
-def _embed(cfg, params, batch):
-    tokens = batch["tokens"]
+def _embed_tokens(cfg, params, tokens):
+    """Embedding rows of ``tokens``, scaled as the config says."""
     x = jnp.take(params["embed"], tokens, axis=0)
     if cfg.scale_embed:
         x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
+    if cfg.embedding_multiplier is not None:
+        x = x * jnp.asarray(cfg.embedding_multiplier, x.dtype)
+    return x
+
+
+def _embed(cfg, params, batch):
+    x = _embed_tokens(cfg, params, batch["tokens"])
     if cfg.frontend == "vit":
         patches = L.dense(batch["patches"], params["front"]["w"])
         pl_ = patches.shape[1]
@@ -330,6 +365,8 @@ def _encoder(cfg, params, frames):
 def _logits(cfg, params, x):
     out_w = params["embed"].T if cfg.tie_embeddings else params["out"]
     lg = L.dense(x, out_w).astype(jnp.float32)
+    if cfg.logits_scaling is not None:
+        lg = lg / cfg.logits_scaling
     if cfg.final_softcap:
         lg = cfg.final_softcap * jnp.tanh(lg / cfg.final_softcap)
     return lg
@@ -598,10 +635,7 @@ def decode_step(cfg: ModelConfig, params, cache, tokens, cache_index):
     paged cache (DESIGN.md §13; -1 marks a finished/empty row whose
     write is routed to the trash page and whose keys are fully masked).
     """
-    x = jnp.take(params["embed"], tokens, axis=0)
-    if cfg.scale_embed:
-        x = x * jnp.asarray(cfg.d_model ** 0.5, x.dtype)
-    x = _residual(cfg, x)
+    x = _residual(cfg, _embed_tokens(cfg, params, tokens))
     B = x.shape[0]
     ci = jnp.asarray(cache_index, jnp.int32)
     if ci.ndim == 1:

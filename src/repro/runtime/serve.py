@@ -640,6 +640,26 @@ class DecodeEngine:
 
         return EXEC_CACHE.get(key, build)
 
+    def cache_bytes(self) -> dict:
+        """Device bytes of the carried decode cache, for the engine and
+        per slot: the paged K/V pool (``kv_pool``; ``kv_per_position``,
+        keys and values of one position over every attention layer) and
+        the recurrent SSM state with its conv windows (``recurrent``;
+        ``recurrent_per_slot``). Page tables are not counted. One
+        snapshot holds as much again."""
+        kv = kv_pos = rec = 0
+        for name, kind in zip(lm.slot_names(self.cfg), self.cfg.pattern):
+            ent = self.st["cache"][name]
+            if kind in ("ssm", "mamba2"):
+                rec += sum(a.size * a.dtype.itemsize for a in ent.values())
+                continue
+            for a in (ent["self"]["k"], ent["self"]["v"]):
+                kv += a.size * a.dtype.itemsize
+                kv_pos += a.size * a.dtype.itemsize // (a.shape[1]
+                                                         * a.shape[3])
+        return {"kv_pool": kv, "kv_per_position": kv_pos,
+                "recurrent": rec, "recurrent_per_slot": rec // self.slots}
+
     # -- host-side protocol --------------------------------------------- #
     @property
     def live(self) -> int:

@@ -109,6 +109,7 @@ def test_full_config_param_count(arch):
         "zamba2-2.7b": 2.7e9,
         "mamba2-1.3b": 1.3e9,
         "seamless-m4t-large-v2": 2.3e9,
+        "granite-4.0-h-micro": 3.2e9,
     }[cfg.name]
     assert 0.5 * expected < n < 1.7 * expected, (cfg.name, n, expected)
 
@@ -133,7 +134,8 @@ def test_long500k_skips_documented():
     """Exactly the SSM/hybrid archs run long_500k (DESIGN.md §6)."""
     runs = [a for a in ARCHS
             if shape_supported(get_config(a), SHAPES["long_500k"])[0]]
-    assert sorted(runs) == ["mamba2_1p3b", "zamba2_2p7b"]
+    assert sorted(runs) == ["granite_4_h_micro", "mamba2_1p3b",
+                            "zamba2_2p7b"]
 
 
 @pytest.mark.parametrize("arch", ["mixtral_8x7b", "moonshot_v1_16b_a3b"])
@@ -144,3 +146,60 @@ def test_moe_capacity_drops_tokens_gracefully(arch):
     loss, _ = jax.jit(lambda p, b: lm.train_loss(cfg, p, b))(
         params, _batch(cfg))
     assert np.isfinite(float(loss))
+
+
+def _granite_or_mamba2(arch):
+    cfg = reduced(get_config("granite_3_2b" if arch == "granite_3_2b"
+                             else "mamba2_1p3b"))
+    if arch == "mamba2":
+        cfg = cfg.replace(pattern=("mamba2",), residual_in_fp32=True,
+                          norm_eps=1e-5)
+    return cfg
+
+
+def _prefill_and_step(cfg, params):
+    """Prefill logits of 12 tokens and one decode step's after them."""
+    toks = _batch(cfg, B=2, T=13, seed=7)["tokens"]
+    lg, cache = jax.jit(lambda p, t: lm.prefill(cfg, p, {"tokens": t},
+                                                max_len=13))(
+        params, toks[:, :12])
+    step, _ = jax.jit(lambda p, c, t: lm.decode_step(cfg, p, c, t,
+                                                     jnp.int32(12)))(
+        params, cache, toks[:, 12:])
+    return np.asarray(lg), np.asarray(step)
+
+
+@pytest.mark.parametrize("arch", ["granite_3_2b", "mamba2"])
+@pytest.mark.parametrize("field", ["attn_scale", "embedding_multiplier",
+                                   "residual_multiplier", "logits_scaling"])
+def test_granite_scalars_at_their_defaults_change_nothing(arch, field):
+    """Each scalar granite-4.0-h-micro added to ``ModelConfig`` is None by
+    default, and then the logits of prefill and of a decode step are
+    bitwise those of the scalar at its neutral value (the softmax scale
+    hd ** -0.5, multipliers and divisor 1), which is what the path
+    computed before the field existed. Set otherwise, it changes them
+    wherever the model has the part it scales (no attention in mamba2)."""
+    cfg = _granite_or_mamba2(arch)
+    assert getattr(cfg, field) is None
+    params = lm.init_params(cfg, jax.random.PRNGKey(4))
+    neutral = {"attn_scale": cfg.hd ** -0.5}.get(field, 1.0)
+    base = _prefill_and_step(cfg, params)
+    same = _prefill_and_step(cfg.replace(**{field: neutral}), params)
+    other = _prefill_and_step(cfg.replace(**{field: 0.5}), params)
+    for a, b in zip(base, same):
+        assert np.array_equal(a, b)
+    scales_a_part = not (arch == "mamba2" and field == "attn_scale")
+    assert any(not np.array_equal(a, c) for a, c in zip(base, other)) \
+        == scales_a_part
+
+
+@pytest.mark.parametrize("arch", ["granite_3_2b", "mamba2"])
+def test_nope_drops_rotary_only_where_there_is_attention(arch):
+    """``rope_theta=None`` (NoPE) changes granite's logits and leaves the
+    attention-free mamba2's bitwise as they were."""
+    cfg = _granite_or_mamba2(arch)
+    params = lm.init_params(cfg, jax.random.PRNGKey(4))
+    base = _prefill_and_step(cfg, params)
+    nope = _prefill_and_step(cfg.replace(rope_theta=None), params)
+    changed = any(not np.array_equal(a, b) for a, b in zip(base, nope))
+    assert changed == (arch == "granite_3_2b")

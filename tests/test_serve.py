@@ -187,7 +187,8 @@ _HLO_INSTR = re.compile(r"^\s*(?:ROOT\s+)?%\S+ = (.+?) ([\w-]+)\(", re.M)
 
 
 @pytest.mark.parametrize("arch", ["granite_3_2b", "mamba2_1p3b",
-                                  "gemma2_2b", "mamba2"])
+                                  "gemma2_2b", "mamba2",
+                                  "granite_4_h_micro"])
 def test_wave_never_copies_the_stacked_cache(arch):
     """The decode cache rides the layer scan's carry and each layer
     writes its rows in place: no ``copy`` or ``broadcast`` in the

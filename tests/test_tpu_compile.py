@@ -264,3 +264,57 @@ def test_mamba2_wave_writes_both_states_in_place(one_chip):
     for _, line, typ, src in copies:
         assert " copy(" in line and src is not None, line
         assert typ != src, f"a window copy that keeps its layout: {line}"
+
+
+def test_hybrid_wave_writes_pool_and_states_in_place(one_chip):
+    """granite-4.0-h-micro's serving wave at full width, 16 slots x
+    4,096 positions in pages of 16: one attention slot's K/V pool
+    ``bf16[4,4097,8,16,64]`` beside nine Mamba2 slots' f32 SSM states
+    ``f32[4,16,64,64,128]`` and conv windows ``bf16[4,16,3,4352]``, all in
+    the carried cache. No decode step copies any of them: the states are
+    not copied at all, the pool and the windows at most once into and
+    once out of the loop's layout each, at the executable's boundary."""
+    from repro.configs import get_config
+    from repro.models import lm
+    from repro.runtime.serve import DecodeEngine
+
+    cfg = get_config("granite_4_h_micro")
+    waves = []
+
+    def state():
+        eng = DecodeEngine(cfg, None, slots=16, page_size=16, max_ctx=4096,
+                           max_new_cap=512)
+        waves.append(eng._wave_fn)
+        return eng.st
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), tree)
+
+    st = jax.eval_shape(state)
+    params = jax.eval_shape(functools.partial(lm.init_params, cfg),
+                            jax.random.PRNGKey(0))
+    cache = st["cache"]
+    assert cache["5_attn"]["self"]["k"].shape == (4, 4097, 8, 16, 64)
+    mamba = [n for n in cache if n.endswith("_mamba2")]
+    assert len(mamba) == 9
+    for n in mamba:
+        assert cache[n]["state"].shape == (4, 16, 64, 64, 128)
+        assert cache[n]["conv"].shape == (4, 16, 3, 4352)
+    compiled = waves[0].lower(
+        on_chip(params), on_chip(st),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)).compile()
+    mem = compiled.memory_analysis()
+    print("hybrid wave, 16 x 4096: arguments", mem.argument_size_in_bytes,
+          "temporaries", mem.temp_size_in_bytes)
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo and "mamba2_state_step" in hlo
+    assert not _pool_copies(hlo, "f32[4,16,64,64,128]")
+    for shape, most in (("bf16[4,4097,8,16,64]", 4),
+                        ("bf16[4,16,3,4352]", 18)):
+        copies = _pool_copies(hlo, shape)
+        assert all(entry for entry, _, _, _ in copies), copies
+        assert len(copies) <= most, (shape, copies)
+        for _, line, typ, src in copies:
+            assert " copy(" in line and src is not None, line
+            assert typ != src, f"a copy that keeps its layout: {line}"
